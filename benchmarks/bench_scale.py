@@ -12,11 +12,14 @@ bounded panel.  Both are bitwise identical (tests/test_scale.py).
 Sections of ``BENCH_scale.json``:
 
 - ``large_build``   the n_t >= 20k, p >= 256 regime.  Dense and
-                    budgeted builds run in subprocesses under an
-                    address-space cap (``RLIMIT_AS``) sized between the
-                    two footprints: the dense build OOMs, the budgeted
-                    build fits.  Uncapped wall-clock and measured peak
-                    RSS are recorded for both, plus the analytic
+                    budgeted builds run in CPU subprocesses
+                    (``JAX_PLATFORMS=cpu``: host memory is what the cap
+                    bounds, and a child never needs the chip its parent
+                    may hold) under an address-space cap
+                    (``RLIMIT_AS``) sized between the two footprints:
+                    the dense build OOMs, the budgeted build fits.
+                    Uncapped wall-clock and measured peak RSS are
+                    recorded for both, plus the analytic
                     workspace-elems accounting per configuration.
 - ``large_fit``     full budgeted fits at the same regime across QP
                     engines (``fista``, ``pallas_fused``,
@@ -88,14 +91,15 @@ print(json.dumps({
 
 
 def _run_build(*, cap_bytes, dense, V, T, N, p, max_elems, timeout=900):
-    """One (possibly capped) invariant build in a subprocess."""
+    """One (possibly capped) invariant build in a CPU subprocess."""
     child = _CHILD.replace("@ROOT@", repr(ROOT))
     args = [sys.executable, "-c", child, str(cap_bytes),
             "0" if dense else "1", str(V), str(T), str(N), str(p),
             str(max_elems)]
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     try:
         out = subprocess.run(args, capture_output=True, text=True,
-                             timeout=timeout)
+                             timeout=timeout, env=env)
     except subprocess.TimeoutExpired:
         return {"ok": False, "oom": False, "error": "timeout"}
     if out.returncode == 0:
@@ -141,7 +145,7 @@ def _bench_large_build(*, V=2, T=1, N=20000, p=256, max_elems=2 ** 27):
     rec = {
         "config": {"V": V, "T": T, "N": N, "p": p,
                    "max_elems": max_elems, "cap_gb": round(cap / 1e9, 2),
-                   "backend": jax.default_backend()},
+                   "backend": "cpu"},
         "dense": _workspace_elems(V, T, N, None),
         "budgeted": _workspace_elems(V, T, N, budget),
     }

@@ -12,8 +12,11 @@ target + rich source task):
 - every sampled response is asserted EXACTLY equal to the unbatched
   computation (``PredictModel.decide_rows``) — the benchmark proves the
   batching is invisible in the values while it measures it;
-- the same sweep runs single-device in-process and multi-device in a
-  subprocess with forced host devices (round-robin across 2).
+- the same sweep runs on one device and, when the process sees more
+  than one, round-robin across all of ``jax.devices()`` — in this
+  process, so a chip is never needed by a child of a process that holds
+  it (on the CPU, ``XLA_FLAGS=--xla_force_host_platform_device_count=2``
+  gives the multi-device sweep two host devices).
 
 Outputs ``BENCH_serve.json`` (repo root on a full run, ``--out PATH``
 anywhere — the CI serve lane uploads the fast variant as an artifact)
@@ -23,8 +26,6 @@ with p50/p99 request latency (ms) and requests/sec per window, and the
 import argparse
 import json
 import os
-import subprocess
-import sys
 import threading
 import time
 
@@ -107,32 +108,10 @@ def _load(model: PredictModel, *, window_ms: float, duration_s: float,
                               "p50_ms", "p99_ms", "rps", "devices")}}
 
 
-def _sweep(model, windows, duration_s, devices=None) -> list:
+def _sweep(model, windows, duration_s, devices) -> list:
     _warmup(model)
     return [_load(model, window_ms=w, duration_s=duration_s,
                   devices=devices) for w in windows]
-
-
-def _multi_device_sweep(fast: bool, windows, duration_s) -> list:
-    """The same sweep under 2 forced host devices, in a subprocess
-    (device count is fixed at jax init, so it cannot change here)."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--worker",
-         "--windows", ",".join(str(w) for w in windows),
-         "--duration", str(duration_s)]
-        + (["--fast"] if fast else []),
-        env=env, capture_output=True, text=True, timeout=600,
-        cwd=os.path.dirname(os.path.abspath(__file__)))
-    assert out.returncode == 0, f"worker failed:\n{out.stderr}"
-    return json.loads(out.stdout.splitlines()[-1])
-
-
-def _worker(fast: bool, windows, duration_s) -> None:
-    model = _fitted_model(fast)
-    recs = _sweep(model, windows, duration_s, devices=jax.devices())
-    print(json.dumps(recs), flush=True)
 
 
 def run(fast: bool = False, out: str = None) -> dict:
@@ -140,8 +119,10 @@ def run(fast: bool = False, out: str = None) -> dict:
     duration_s = 1.0 if fast else 3.0
     model = _fitted_model(fast)
 
-    single = _sweep(model, windows, duration_s)
-    multi = _multi_device_sweep(fast, windows, duration_s)
+    devices = jax.devices()
+    single = _sweep(model, windows, duration_s, devices[:1])
+    multi = (_sweep(model, windows, duration_s, devices)
+             if len(devices) > 1 else None)
 
     recs = {
         "config": {"model_shape": list(model.shape),
@@ -190,14 +171,5 @@ if __name__ == "__main__":
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--out", default=None,
                     help="write BENCH_serve.json to this path")
-    ap.add_argument("--worker", action="store_true",
-                    help="internal: multi-device subprocess mode")
-    ap.add_argument("--windows", default="")
-    ap.add_argument("--duration", type=float, default=1.0)
     args = ap.parse_args()
-    if args.worker:
-        _worker(args.fast,
-                [float(w) for w in args.windows.split(",")],
-                args.duration)
-    else:
-        main(args.fast, args.out)
+    main(args.fast, args.out)

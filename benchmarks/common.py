@@ -23,14 +23,16 @@ except ModuleNotFoundError:  # fallback: run from a bare checkout
     sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "src"))
 
+from repro import compile_cache                             # noqa: E402
 from repro.api import CSVM, DSVM, DTSVM, SolverConfig      # noqa: E402
 from repro.api import dsvm_overrides, evaluate, sweep_fit  # noqa: E402,F401
 from repro.core import graph                                # noqa: E402
 from repro.data import synthetic                            # noqa: E402
 from repro.obs import timing as obs_timing                  # noqa: E402
 
-RESULTS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "results")
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RESULTS = os.path.join(ROOT, "results")
+compile_cache.enable(ROOT)
 
 # Paper Section IV defaults
 C = 0.01

@@ -92,13 +92,8 @@ def main(fast=True, variant="all"):
     for name, kw in VARIANTS:
         if variant != "all" and variant != name:
             continue
-        try:
-            rec = measure(name, kw, data, A, iters=iters,
-                          qp_iters=qp_iters, repeats=repeats)
-        except Exception as e:  # a rung may be unbuildable on this host
-            emit(f"hillclimb_{name}", 0.0,
-                 f"ERROR {type(e).__name__}: {e}")
-            continue
+        rec = measure(name, kw, data, A, iters=iters, qp_iters=qp_iters,
+                      repeats=repeats)
         if baseline is None:
             baseline = rec
         rec["speedup_vs_fista"] = baseline["fit_s"] / rec["fit_s"]

@@ -8,8 +8,10 @@ what JAX actually *traces*:
   primitive** (unordered-reduction scatters, stateful RNG — the
   nondeterministic-order class the bitwise contract forbids; the
   round-*keyed* threefry stream the lossy fabric uses is deterministic
-  and allowed) and any **denied dtype** (f64 — only possible when
-  ambient x64 config leaks in; bf16/f16 — never intentional here).
+  and allowed), any **denied dtype** (f64 — only possible when
+  ambient x64 config leaks in; bf16/f16 — never intentional here) and
+  any f32 ``dot_general`` left at **default precision** (one bf16 pass
+  on a TPU, so a fit there drifts far from the f32 result).
 - :func:`audit_entry_points` applies that to the paths the contract
   actually covers: ``compile_problem(...).step``, ``compile_sweep``'s
   batched step, the async fabric round (``_fabric_step``), and the
@@ -47,6 +49,9 @@ DENY_PRIMS = frozenset({
 #: never intentional in this repo.
 DENY_DTYPES = frozenset({"float64", "bfloat16", "float16"})
 
+#: the precision every f32 contraction on a contract path pins
+_F32_PRECISION = jax.lax.Precision.HIGHEST
+
 
 # ----------------------------------------------------------------------
 # jaxpr walking
@@ -77,7 +82,8 @@ def audit_fn(fn: Callable, *args, name: Optional[str] = None,
     """Trace ``fn(*args, **kwargs)`` and audit the full jaxpr.
 
     Returns :class:`~repro.analysis.linter.Finding` objects with rule
-    ids ``jaxpr-denied-prim`` / ``jaxpr-denied-dtype``; the ``path``
+    ids ``jaxpr-denied-prim`` / ``jaxpr-denied-dtype`` /
+    ``jaxpr-default-precision``; the ``path``
     field carries the entry-point name (there is no source line for a
     jaxpr equation).  An empty list means the traced program is clean.
     """
@@ -106,6 +112,15 @@ def audit_fn(fn: Callable, *args, name: Optional[str] = None,
                     "pinned-f32 policy forbids it on contract paths "
                     "(ambient x64 leak or an unintended low-precision "
                     "cast)"))
+        if (prim == "dot_general"
+                and all(str(v.aval.dtype) == "float32" for v in eqn.invars)
+                and eqn.params.get("precision") != (_F32_PRECISION,) * 2
+                and ("precision", prim) not in seen):
+            seen.add(("precision", prim))
+            findings.append(Finding(
+                "jaxpr-default-precision", name, 0,
+                "f32 dot_general without precision='highest' — a TPU "
+                "runs it as one bf16 pass"))
     return findings
 
 

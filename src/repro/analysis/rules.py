@@ -507,21 +507,20 @@ class UntiledGramCall(Rule):
 
 
 class EnvDependentDtype(Rule):
-    """Behavior keyed on the x64 switch outside ``dist.compat``.
+    """Behavior keyed on the x64 switch.
 
-    ``dist/compat.py`` is the single blessed shim for version- and
-    env-dependent behavior; an ``jax_enable_x64`` read/write anywhere
-    else makes numeric results depend on ambient process config — the
-    opposite of the pinned-dtype policy (everything f32 unless a leaf
-    says otherwise).
+    A ``jax_enable_x64`` read/write anywhere in the package makes
+    numeric results depend on ambient process config — the opposite of
+    the pinned-dtype policy (everything f32 unless a leaf says
+    otherwise).
     """
     id = "env-dependent-dtype"
-    summary = "jax_enable_x64 touched outside dist.compat"
+    summary = "jax_enable_x64 touched in package code"
     history = ("standing policy: dtypes are pinned per-leaf; PR 6's "
                "downcast bug was only possible because behavior "
                "differed with ambient x64 config")
     paths = None
-    exclude = ("analysis/", "dist/compat.py") + SUBSTRATE_PATHS
+    exclude = ("analysis/",) + SUBSTRATE_PATHS
 
     _KEYS = ("jax_enable_x64", "JAX_ENABLE_X64")
 
@@ -532,15 +531,14 @@ class EnvDependentDtype(Rule):
                     and node.value in self._KEYS):
                 yield self.finding(
                     mod, node.lineno,
-                    f"{node.value!r} referenced outside dist.compat — "
-                    "env-keyed dtype behavior belongs in the compat "
-                    "shim only")
+                    f"{node.value!r} referenced — pin the dtype on the "
+                    "leaf instead of keying behavior on the x64 switch")
             elif (isinstance(node, ast.Attribute)
                     and node.attr == "jax_enable_x64"):
                 yield self.finding(
                     mod, node.lineno,
-                    "jax_enable_x64 attribute touched outside "
-                    "dist.compat")
+                    "jax_enable_x64 attribute touched — pin the dtype "
+                    "on the leaf instead")
 
 
 # ----------------------------------------------------------------------

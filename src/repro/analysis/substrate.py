@@ -8,7 +8,8 @@ QUARANTINED, not deleted, per ``docs/substrates.md`` (they may be
 revived the way ``checkpoint`` was in the durable-session work).  This
 module mechanizes that judgment: it builds the static import graph of
 ``src/repro`` and walks it from the public roots (``repro.api``,
-``repro.store``, ``repro.serve``, ``repro.data``).  Whatever the walk
+``repro.store``, ``repro.serve``, ``repro.data``,
+``repro.compile_cache``).  Whatever the walk
 cannot reach is reported as substrate — an *informational* section of
 the analysis report, never a CI failure.
 """
@@ -19,9 +20,10 @@ import os
 from typing import Dict, List, Set
 
 #: subpackages whose modules seed the reachability walk — the public
-#: surface (API, durable store, serving) plus the dataset generators.
+#: surface (API, durable store, serving), the dataset generators and the
+#: compile-cache helper the scripts call at start-up.
 ROOT_PACKAGES = ("repro.api", "repro.store", "repro.serve",
-                 "repro.data")
+                 "repro.data", "repro.compile_cache")
 
 #: the analyzer itself: excluded from both live and substrate sets.
 TOOLING_PACKAGES = ("repro.analysis",)

@@ -261,7 +261,8 @@ def _qp_rows(K_rows, q_rows, hi_rows, lam0_rows, L, *, iters: int,
     import jax.numpy as jnp
 
     step = 1.0 / L                                        # (V, T)
-    matvec = jax.vmap(jax.vmap(lambda Kr, yf: Kr @ yf))   # rows of K @ y
+    matvec = jax.vmap(jax.vmap(                          # rows of K @ y
+        lambda Kr, yf: jnp.matmul(Kr, yf, precision="highest")))
     gather = lambda y: jax.lax.all_gather(y, axis, axis=2, tiled=True)
     lam = jnp.clip(lam0_rows, 0.0, hi_rows)
 
@@ -331,10 +332,11 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
     partial sums combined with one psum
     (``obs.telemetry.collect_shard_diagnostics``).
     """
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    from repro.dist import compat
     from repro.dist import sharding as shard_lib
     from repro.engine import invariants as inv_lib
     from repro.kernels import ops as kops
@@ -357,9 +359,10 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
     prob_spec, state_spec = shard_lib.sample_specs(axis)
     tile = None if budget is None else budget.tile
 
-    @compat.shard_map(mesh=mesh, in_specs=(state_spec, prob_spec),
-                      out_specs=(state_spec, shard_lib.P()),
-                      check_vma=False)
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(state_spec, prob_spec),
+                       out_specs=(state_spec, shard_lib.P()),
+                       check_vma=False)
     def run_shard(st, pr):
         # -- invariants: counts/u/a replicated, Z/hi/K as row panels --
         ntp, nbr, u, a, hi_rows = inv_lib._masks_part(pr)
@@ -388,10 +391,12 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
                            iters=qp_iters, axis=axis, qp_solver=qp_solver)
             if reduce == "gather":
                 lam_full = jax.lax.all_gather(lam, axis, axis=2, tiled=True)
-                zl = jnp.einsum("vtn,vtnd->vtd", lam_full, Z_full)
+                zl = jnp.einsum("vtn,vtnd->vtd", lam_full, Z_full,
+                                precision="highest")
             else:
                 zl = jax.lax.psum(
-                    jnp.einsum("vtn,vtnd->vtd", lam, Z_rows), axis)
+                    jnp.einsum("vtn,vtnd->vtd", lam, Z_rows,
+                               precision="highest"), axis)
             r_new, alpha, beta = engine_plan.consensus_update(
                 pr, s, u, ntp, nbr, f, zl, nbr_reduce)
             return core.DTSVMState(r=r_new, alpha=alpha, beta=beta, lam=lam)

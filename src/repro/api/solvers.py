@@ -430,7 +430,8 @@ class CSVM:
         X = jnp.asarray(X, jnp.float32)
         if X.ndim == 2:
             X = X[None]
-        return jnp.einsum("tnp,tp->tn", X, self.w_) + self.b_[:, None]
+        return (jnp.einsum("tnp,tp->tn", X, self.w_, precision="highest")
+                + self.b_[:, None])
 
     def predict(self, X) -> jnp.ndarray:
         """Predicted labels in {-1, +1}: (T, n) for (T, n, p) inputs."""

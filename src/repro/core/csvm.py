@@ -39,7 +39,8 @@ def csvm_fit(X: jnp.ndarray, y: jnp.ndarray, C: float,
     q = mask
     hi = C * mask
     lam = qp_lib.solve_box_qp_fista(K, q, hi, iters=qp_iters)
-    w_aug = (Z * ainv[None, :]).T @ lam          # diag(ainv) Z^T lam
+    w_aug = jnp.matmul((Z * ainv[None, :]).T, lam,
+                       precision="highest")          # diag(ainv) Z^T lam
     return w_aug[:p], w_aug[p]
 
 
@@ -58,7 +59,7 @@ def csvm_fit_tasks(X: jnp.ndarray, y: jnp.ndarray, C: float,
 
 
 def csvm_decision(w: jnp.ndarray, b: jnp.ndarray, X: jnp.ndarray):
-    return X @ w + b
+    return jnp.matmul(X, w, precision="highest") + b
 
 
 def csvm_risk(w, b, X, y) -> jnp.ndarray:

@@ -121,7 +121,8 @@ def _default_nbr_reduce(prob: DTSVMProblem):
     """Sum an (V,T,D) array over each node's active neighbors (dense adj)."""
     adjf = prob.adj.astype(jnp.float32)
     # repro: noqa[raw-einsum-in-plan] — deliberate: this einsum DEFINES the neighbor-sum semantics every backend (incl. Fabric.reduce) must match bitwise; golden fixtures pin it
-    return lambda arr: jnp.einsum("vu,utd->vtd", adjf, arr)
+    return lambda arr: jnp.einsum("vu,utd->vtd", adjf, arr,
+                                  precision="highest")
 
 
 def _counts(prob: DTSVMProblem, nbr_counts: Optional[jnp.ndarray] = None):
@@ -219,7 +220,7 @@ def dtsvm_step(state: DTSVMState, prob: DTSVMProblem,
     lam = solve(K, q, hi, state.lam)                        # eq. (6)
 
     # repro: noqa[raw-einsum-in-plan] — deliberate: legacy oracle mirrors engine/plan.py's zl contraction exactly; tests assert oracle == engine bitwise
-    zl = jnp.einsum("vtn,vtnd->vtd", lam, Z)                # X^T Y lam
+    zl = jnp.einsum("vtn,vtnd->vtd", lam, Z, precision="highest")  # X^T Y lam
     rhs = jnp.concatenate([zl, zl], axis=-1) - f            # [I,I]^T (...) - f
     r_new = rhs / u                                          # eq. (7)
     act = prob.active[..., None]
@@ -268,7 +269,8 @@ def decision_values(r: jnp.ndarray, X: jnp.ndarray) -> jnp.ndarray:
     p = X.shape[-1]
     w = r[..., :p] + r[..., p + 1: 2 * p + 1]
     b = r[..., p] + r[..., 2 * p + 1]
-    return jnp.einsum("...np,...p->...n", X, w) + b[..., None]
+    return (jnp.einsum("...np,...p->...n", X, w, precision="highest")
+            + b[..., None])
 
 
 def risks(r: jnp.ndarray, X: jnp.ndarray, y: jnp.ndarray,
@@ -296,7 +298,7 @@ def consensus_residuals(state: DTSVMState, prob: DTSVMProblem):
     r_act = r * act
     deg = jnp.maximum(jnp.einsum(
         "vu,ut->vt", A, prob.active), 1)[..., None]
-    nbr_mean = jnp.einsum("vu,utd->vtd", A, r_act) / deg
+    nbr_mean = jnp.einsum("vu,utd->vtd", A, r_act, precision="highest") / deg
     node_res = jnp.max(jnp.abs((r - nbr_mean) *
                                act * (deg > 0)))
     return task_res, node_res

@@ -31,7 +31,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import dtsvm
-from repro.dist import compat
 
 
 def make_node_mesh(V: int, axis: str = "nodes") -> Mesh:
@@ -54,7 +53,7 @@ def _nbr_reduce_for(adjf, *, axis: str, topology: str):
     else:
         def nbr_reduce(arr):
             full = jax.lax.all_gather(arr, axis, axis=0, tiled=True)  # (V,T,D)
-            return jnp.einsum("vu,utd->vtd", adjf, full)
+            return jnp.einsum("vu,utd->vtd", adjf, full, precision="highest")
     return nbr_reduce
 
 
@@ -118,7 +117,7 @@ def build_runner(mesh: Mesh, *, axis: str = "nodes",
     node, repl, state_spec, prob_spec, _ = _node_specs(axis)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(state_spec, prob_spec, node, repl),
         check_vma=False, out_specs=state_spec)
     def run_shard(st, pr, adj_r, act_g):
@@ -152,7 +151,7 @@ def build_planned_runner(mesh: Mesh, *, axis: str = "nodes",
     node, repl, state_spec, prob_spec, inv_spec = _node_specs(axis)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh, in_specs=(prob_spec, node, repl),
+        jax.shard_map, mesh=mesh, in_specs=(prob_spec, node, repl),
         check_vma=False, out_specs=inv_spec)
     def compile_shard(pr, adj_r, act_g):
         adjf = adj_r.astype(jnp.float32)
@@ -161,7 +160,7 @@ def build_planned_runner(mesh: Mesh, *, axis: str = "nodes",
                                           budget=budget)
 
     @functools.partial(
-        compat.shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(state_spec, prob_spec, inv_spec, node),
         check_vma=False, out_specs=state_spec)
     def step_shard(st, pr, inv, adj_r):

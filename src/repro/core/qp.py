@@ -49,7 +49,7 @@ def solve_box_qp_pg(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
     lam = _project(lam, hi)
 
     def body(_, lam):
-        grad = q - K @ lam
+        grad = q - jnp.matmul(K, lam, precision="highest")
         return _project(lam + step * grad, hi)
 
     return jax.lax.fori_loop(0, iters, body, lam)
@@ -68,7 +68,7 @@ def solve_box_qp_fista(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
 
     def body(_, state):
         lam, y, t = state
-        grad = q - K @ y
+        grad = q - jnp.matmul(K, y, precision="highest")
         lam_new = _project(y + step * grad, hi)
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         y_new = lam_new + ((t - 1.0) / t_new) * (lam_new - lam)
@@ -79,10 +79,12 @@ def solve_box_qp_fista(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
 
 
 def qp_objective(K, q, lam):
-    return -0.5 * lam @ (K @ lam) + q @ lam
+    Klam = jnp.matmul(K, lam, precision="highest")
+    return (-0.5 * jnp.matmul(lam, Klam, precision="highest")
+            + jnp.matmul(q, lam, precision="highest"))
 
 
 def kkt_residual(K, q, hi, lam) -> jnp.ndarray:
     """|| lam - proj(lam + grad) ||_inf — zero iff lam is optimal."""
-    grad = q - K @ lam
+    grad = q - jnp.matmul(K, lam, precision="highest")
     return jnp.max(jnp.abs(lam - _project(lam + grad, hi)))

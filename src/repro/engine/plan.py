@@ -111,7 +111,8 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
         lam = engine(inv.K, q, inv.hi, state.lam,
                      iters=qp_iters, L=inv.L)                  # eq. (6)
         # repro: noqa[raw-einsum-in-plan] — deliberate: mul+reduce would materialize a (V,T,N,D) temporary; batching stability is pinned by the fig2-fig7 golden fixtures across all backends
-        zl = jnp.einsum("vtn,vtnd->vtd", lam, Z)               # X^T Y lam
+        zl = jnp.einsum("vtn,vtnd->vtd", lam, Z,
+                        precision="highest")                   # X^T Y lam
     r_new, alpha, beta = consensus_update(prob, state, u, ntp, nbr, f, zl,
                                           nbr_reduce)
     return core.DTSVMState(r=r_new, alpha=alpha, beta=beta, lam=lam)

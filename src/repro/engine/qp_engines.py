@@ -173,12 +173,12 @@ def solve_factored_multi(Z, a, q, hi, lam0=None, *, iters: int, L):
 
     def body(_, lam):
         # repro: noqa[raw-einsum-in-plan] — deliberate: the factored operator's defining contraction; the mode is opt-in and validated by risk deltas, never claimed bitwise vs the materialized plan
-        zt = jnp.einsum("...n,...nd->...d", lam, Z)
+        zt = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
         # repro: noqa[raw-einsum-in-plan] — deliberate: second half of the O(ND) factored matvec (see above)
-        Klam = jnp.einsum("...nd,...d->...n", Z, a * zt)
+        Klam = jnp.einsum("...nd,...d->...n", Z, a * zt, precision="highest")
         return jnp.clip(lam + gamma * (q - Klam), 0.0, hi)
 
     lam = jax.lax.fori_loop(0, iters, body, lam)
     # repro: noqa[raw-einsum-in-plan] — deliberate: same formula as plan_step's zl einsum (the factored fold reuses the final Z^T lam)
-    zl = jnp.einsum("...n,...nd->...d", lam, Z)
+    zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
     return lam, zl

@@ -36,6 +36,7 @@ neighbor sums), matching the single-host path bitwise (tests/test_dist).
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Mapping, Optional, Sequence
 
 import jax
@@ -247,7 +248,6 @@ class SweepPlan:
         from jax.sharding import PartitionSpec as P
 
         from repro.core import dtsvm_dist
-        from repro.dist import compat
 
         if topology not in ("graph", "ring"):
             raise ValueError(f"unknown topology {topology!r}; "
@@ -287,9 +287,9 @@ class SweepPlan:
                                      lam=swnd)
         qp_iters, qp_solver = self.qp_iters, self.qp_solver
 
-        @compat.shard_map(mesh=mesh,
-                          in_specs=(state_spec, prob_spec, inv_spec, nd),
-                          out_specs=state_spec, check_vma=False)
+        @functools.partial(jax.shard_map, mesh=mesh,
+                           in_specs=(state_spec, prob_spec, inv_spec, nd),
+                           out_specs=state_spec, check_vma=False)
         def run_shard(st, pr, iv, adj_rows):
             if node_axis is not None:
                 nbr_reduce = dtsvm_dist._nbr_reduce_for(
@@ -297,7 +297,8 @@ class SweepPlan:
                     topology=topology)
             else:
                 adjf = adj_rows.astype(jnp.float32)
-                nbr_reduce = lambda arr: jnp.einsum("vu,utd->vtd", adjf, arr)
+                nbr_reduce = lambda arr: jnp.einsum("vu,utd->vtd", adjf,
+                                                      arr, precision="highest")
             step1 = lambda p_, i_, s_: plan_step(
                 p_, i_, s_, qp_iters=qp_iters, qp_solver=qp_solver,
                 nbr_reduce=nbr_reduce)

@@ -70,7 +70,7 @@ def _gram_kernel(zi_ref, zj_ref, a_ref, out_ref):
     zia = zi * a                           # VPU elementwise scale
     out_ref[...] = jax.lax.dot_general(
         zia, zj, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision="highest", preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -81,8 +81,7 @@ def weighted_gram_2d(Z: jnp.ndarray, a: jnp.ndarray, *,
 
     ``interpret`` defaults to platform-derived (compiled on TPU,
     interpret elsewhere)."""
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     N, D = Z.shape
     bn = min(block, max(_next_multiple(N, SUBLANE), SUBLANE))
     spec = gram_launch_spec(N, N, D, bn, bn)
@@ -128,7 +127,9 @@ def weighted_gram_tiled(Zm: jnp.ndarray, a: jnp.ndarray,
     computed in ``(tile_m, tile_n)`` output blocks (aligned via
     ``align_tile``).  ``weighted_gram_tiled(Z, a, Z)`` is the square
     kernel; a row-panel call is one streamed chunk of the large-n build.
+    ``interpret`` as in ``launch.default_interpret``.
     """
+    interpret = default_interpret(interpret)
     M, D = Zm.shape
     N, _ = Zn.shape
     tm, tn = align_tile(tile, M, N)

@@ -12,7 +12,7 @@ disagree about what is launched.
 """
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 #: f32 TPU layout: second-minor (sublane) x minor (lane) minimum tile.
 SUBLANE = 8
@@ -47,12 +47,20 @@ def next_multiple(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def default_interpret() -> bool:
-    """Platform-derived default for the kernels' ``interpret`` kwarg:
-    compiled on TPU, interpret mode everywhere else.  Resolved at trace
-    time (it is a static jit argument), so a kernel called with
-    ``interpret=None`` never silently runs the Python interpreter on a
-    TPU — the bug the old hard-coded ``interpret=True`` defaults had."""
+def default_interpret(interpret: Optional[bool] = None) -> bool:
+    """The kernels' ``interpret`` flag, resolved from the platform:
+    ``None`` means compiled on a TPU and interpret mode everywhere else.
+    Interpret mode on a TPU is refused, so no kernel on the chip can run
+    the Python interpreter while it claims to run compiled.  ``False``
+    off a TPU is allowed: it lowers the compiled kernel, which is how
+    the kernels are compiled for a described (not attached) TPU.
+    Resolved at trace time (``interpret`` is a static jit argument)."""
     import jax  # local: keep this module importable as pure geometry data
 
-    return jax.default_backend() != "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("interpret=True on a TPU: the Pallas kernels run "
+                         "compiled there (interpret mode is for the CPU)")
+    return bool(interpret)

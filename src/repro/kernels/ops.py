@@ -1,11 +1,14 @@
 """Dispatching wrappers for the Pallas kernels.
 
-On TPU the Pallas kernels run compiled; in this CPU container they run in
-``interpret=True`` mode (the kernel body executes in Python, validating the
-BlockSpec tiling and kernel semantics bit-for-bit against ``ref.py``).
-Because interpret mode is slow, the *default* CPU execution path is the
-jnp oracle; set ``REPRO_USE_PALLAS=1`` to force the interpreted kernels
-(the kernel-suite CI lane and the engine's QP-equivalence tests do this).
+On a TPU the Pallas kernels always run compiled.  On the CPU they can
+only run in ``interpret=True`` mode (the kernel body executes in
+Python, validating the BlockSpec tiling and kernel semantics
+bit-for-bit against ``ref.py``); because that is slow, the *default*
+CPU execution path is the jnp oracle, and ``REPRO_USE_PALLAS=1`` forces
+the interpreted kernels (the kernel-suite CI lane and the engine's
+QP-equivalence tests do this).  ``REPRO_USE_PALLAS`` is the CPU tests'
+switch only: on a TPU a value of ``0`` is refused, and the one way to
+run the oracle there is the explicit :func:`reference_kernels` scope.
 
 Both wrappers are live solve-path code, not just benchmarks:
 
@@ -17,6 +20,8 @@ Both wrappers are live solve-path code, not just benchmarks:
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import os
 
 import jax
@@ -26,18 +31,39 @@ from repro.kernels import gram as gram_kernel
 from repro.kernels import qp_step as qp_kernel
 from repro.kernels import ref
 
+_REFERENCE = contextvars.ContextVar("reference_kernels", default=False)
+
 
 def _use_pallas() -> bool:
-    flag = os.environ.get("REPRO_USE_PALLAS", "auto")
-    if flag == "1":
-        return True
-    if flag == "0":
+    """True when the wrappers run the Pallas kernels, False when they
+    run the jnp oracle (``ref.py``).  Read at trace time."""
+    if _REFERENCE.get():
         return False
-    return jax.default_backend() == "tpu"
+    flag = os.environ.get("REPRO_USE_PALLAS", "auto")
+    if jax.default_backend() == "tpu":
+        if flag == "0":
+            raise RuntimeError(
+                "REPRO_USE_PALLAS=0 on a TPU would run the jnp oracle in "
+                "place of the compiled kernels; it is the CPU tests' "
+                "switch — use kernels.ops.reference_kernels() to run the "
+                "oracle on purpose")
+        return True
+    return flag == "1"
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+@contextlib.contextmanager
+def reference_kernels():
+    """Run the jnp oracle (``ref.py``) in place of the Pallas kernels,
+    on any backend, for the duration of the block — the plain reference
+    a kernel run is checked against.  The scope holds in the calling
+    thread only and is read at trace time, so a jitted function first
+    traced inside the block keeps the oracle in its cache unless it
+    keys the cache on the switch (the streamed Gram builds do)."""
+    token = _REFERENCE.set(True)
+    try:
+        yield
+    finally:
+        _REFERENCE.reset(token)
 
 
 def weighted_gram(Z: jnp.ndarray, a: jnp.ndarray, *,
@@ -57,12 +83,11 @@ def weighted_gram(Z: jnp.ndarray, a: jnp.ndarray, *,
     if not _use_pallas():
         return ref.weighted_gram(Z, a)
     if tile is None:
-        fn = lambda z2, a1: gram_kernel.weighted_gram_2d(
-            z2, a1, interpret=_interpret())
+        fn = gram_kernel.weighted_gram_2d
     else:
         tile = tuple(tile)
         fn = lambda z2, a1: gram_kernel.weighted_gram_tiled(
-            z2, a1, z2, tile=tile, interpret=_interpret())
+            z2, a1, z2, tile=tile)
     batch = Z.shape[:-2]
     if batch:
         flatZ = Z.reshape((-1,) + Z.shape[-2:])
@@ -88,7 +113,7 @@ def weighted_gram_rows(Zm: jnp.ndarray, a: jnp.ndarray, Zn: jnp.ndarray, *,
         return ref.weighted_gram_rows(Zm, a, Zn)
     tile = gram_kernel.DEFAULT_TILE if tile is None else tuple(tile)
     fn = lambda zm, a1, zn: gram_kernel.weighted_gram_tiled(
-        zm, a1, zn, tile=tile, interpret=_interpret())
+        zm, a1, zn, tile=tile)
     batch = Zm.shape[:-2]
     if batch:
         flat = lambda x: x.reshape((-1,) + x.shape[len(batch):])
@@ -107,8 +132,7 @@ def qp_pg_step(lam, K, q, hi, gamma) -> jnp.ndarray:
     leading-aligned, then broadcast across the remaining batch dims."""
     if not _use_pallas():
         return ref.qp_pg_step(lam, K, q, hi, gamma)
-    fn = lambda l1, K2, q1, h1, g0: qp_kernel.qp_pg_step_1d(
-        l1, K2, q1, h1, g0, interpret=_interpret())
+    fn = qp_kernel.qp_pg_step_1d
     batch = lam.shape[:-1]
     gamma = _align_gamma(gamma, batch)
     if batch:
@@ -156,8 +180,7 @@ def qp_pg_multi(lam0, K, q, hi, gamma, *, iters: int, Z=None,
         return ref.qp_pg_multi(lam0, K, q, hi, gamma, iters=iters, Z=Z,
                                precision=precision)
     fn = lambda l0, K2, q1, h1, g0, z2: qp_kernel.qp_pg_multi_1d(
-        l0, K2, q1, h1, g0, iters=iters, Z=z2, precision=precision,
-        interpret=_interpret())
+        l0, K2, q1, h1, g0, iters=iters, Z=z2, precision=precision)
     batch = lam0.shape[:-1]
     gamma = _align_gamma(gamma, batch)
     if not batch:

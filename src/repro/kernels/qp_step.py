@@ -62,7 +62,7 @@ def _qp_step_kernel(K_ref, lamc_ref, lamr_ref, q_ref, hi_ref, gamma_ref,
     # (1, BC) x (BR, BC)^T -> (1, BR): y_r += sum_c K[r, c] lam[c]
     acc_ref[...] += jax.lax.dot_general(
         lam_c, Kb, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision="highest", preferred_element_type=jnp.float32)
 
     @pl.when(j == n_col - 1)
     def _finalize():
@@ -81,8 +81,7 @@ def qp_pg_step_1d(lam, K, q, hi, gamma, *, block: int = DEFAULT_BLOCK,
     never contribute to the matvec (K padding is zero).  ``interpret``
     defaults to platform-derived (compiled on TPU, interpret elsewhere);
     pass it explicitly to pin a mode."""
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     N = lam.shape[0]
     spec = qp_launch_spec(N, block)
     Np = spec.out_shape[1]
@@ -144,6 +143,13 @@ def qp_multi_launch_spec(N: int, iters: int, block: int = DEFAULT_BLOCK,
     )
 
 
+def _matvec_precision(dtype):
+    """f32 K tiles contract at full f32 precision (several MXU passes;
+    the TPU's default is one bf16 pass).  bf16 tiles are exact in one
+    pass, and Mosaic refuses an f32 contraction precision for them."""
+    return "highest" if dtype == jnp.float32 else None
+
+
 def _qp_multi_kernel(K_ref, lam0_ref, q_ref, hi_ref, gamma_ref, out_ref,
                      lam_ref, acc_ref, *, n_row: int, n_col: int,
                      iters: int, bn: int):
@@ -174,6 +180,7 @@ def _qp_multi_kernel(K_ref, lam0_ref, q_ref, hi_ref, gamma_ref, out_ref,
     # (1, BC) x (BR, BC)^T -> (1, BR): y_r += sum_c K[r, c] lam[c]
     acc_ref[...] += jax.lax.dot_general(
         lam_c.astype(Kb.dtype), Kb, (((1,), (1,)), ((), ())),
+        precision=_matvec_precision(Kb.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(j == n_col - 1)
@@ -212,6 +219,7 @@ def _qp_multi_fold_kernel(K_ref, lam0_ref, q_ref, hi_ref, gamma_ref, Z_ref,
     Kb = K_ref[...]
     acc_ref[...] += jax.lax.dot_general(
         lam_c.astype(Kb.dtype), Kb, (((1,), (1,)), ((), ())),
+        precision=_matvec_precision(Kb.dtype),
         preferred_element_type=jnp.float32)
 
     @pl.when(j == n_col - 1)
@@ -226,7 +234,7 @@ def _qp_multi_fold_kernel(K_ref, lam0_ref, q_ref, hi_ref, gamma_ref, Z_ref,
         def _fold_zl():                     # (1, BR) x (BR, Dp) -> (1, Dp)
             zl_ref[...] += jax.lax.dot_general(
                 new_row, Z_ref[...], (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                precision="highest", preferred_element_type=jnp.float32)
 
     @pl.when((j == n_col - 1) & (i == n_row - 1))
     def _next_iteration():
@@ -258,8 +266,7 @@ def qp_pg_multi_1d(lam0, K, q, hi, gamma, *, iters: int, Z=None,
     platform-derived."""
     if precision not in ("f32", "bf16"):
         raise ValueError(f"unknown precision {precision!r}")
-    if interpret is None:
-        interpret = default_interpret()
+    interpret = default_interpret(interpret)
     N = lam0.shape[0]
     fold = Z is not None
     spec = qp_multi_launch_spec(N, iters, block,

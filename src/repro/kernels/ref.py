@@ -12,7 +12,8 @@ def weighted_gram(Z: jnp.ndarray, a: jnp.ndarray) -> jnp.ndarray:
     K = (Y X~) [I,I] U^{-1} [I,I]^T (Y X~)^T with diagonal U.
     """
     # repro: noqa[raw-einsum-in-plan] — deliberate: this oracle DEFINES the Gram semantics the Pallas kernels must reproduce bitwise (interpret-vs-oracle tests)
-    return jnp.einsum("...nd,...d,...md->...nm", Z, a.astype(Z.dtype), Z)
+    return jnp.einsum("...nd,...d,...md->...nm", Z, a.astype(Z.dtype), Z,
+                      precision="highest")
 
 
 def weighted_gram_rows(Zm: jnp.ndarray, a: jnp.ndarray,
@@ -28,7 +29,8 @@ def weighted_gram_rows(Zm: jnp.ndarray, a: jnp.ndarray,
     of which panel it lands in).
     """
     # repro: noqa[raw-einsum-in-plan] — deliberate: identical per-element contraction as weighted_gram (the streamed/sharded builds rely on panel == full bitwise)
-    return jnp.einsum("...nd,...d,...md->...nm", Zm, a.astype(Zm.dtype), Zn)
+    return jnp.einsum("...nd,...d,...md->...nm", Zm, a.astype(Zm.dtype), Zn,
+                      precision="highest")
 
 
 def qp_pg_step(lam: jnp.ndarray, K: jnp.ndarray, q: jnp.ndarray,
@@ -46,7 +48,7 @@ def qp_pg_step(lam: jnp.ndarray, K: jnp.ndarray, q: jnp.ndarray,
     if gamma.ndim:
         gamma = gamma.reshape(gamma.shape + (1,) * (lam.ndim - gamma.ndim))
     # repro: noqa[raw-einsum-in-plan] — deliberate: the matvec oracle the fused Pallas QP step is tested bitwise against
-    grad = q - jnp.einsum("...nm,...m->...n", K, lam)
+    grad = q - jnp.einsum("...nm,...m->...n", K, lam, precision="highest")
     return jnp.clip(lam + gamma * grad, 0.0, hi)
 
 
@@ -89,5 +91,5 @@ def qp_pg_multi(lam0: jnp.ndarray, K: jnp.ndarray, q: jnp.ndarray,
     if Z is None:
         return lam
     # repro: noqa[raw-einsum-in-plan] — deliberate: the zl fold oracle; formula matches plan_step's einsum exactly so the oracle fold is bitwise the unfolded plan path
-    zl = jnp.einsum("...n,...nd->...d", lam, Z)
+    zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
     return lam, zl

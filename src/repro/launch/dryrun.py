@@ -34,7 +34,6 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, SHAPES, get_config, shape_applicable
-from repro.dist import compat
 from repro.dist import sharding as shp
 from repro.launch import mesh as mesh_lib
 from repro.models import model as model_lib
@@ -205,7 +204,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
     try:
         # set_mesh (not a bare `with mesh:`) so the abstract mesh is visible
         # during tracing — transformer.constrain_activations depends on it.
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             cfg, shape, lowered = build_lowering(arch, shape_name, mesh, mode)
             t_lower = time.time() - t0
             compiled = lowered.compile()

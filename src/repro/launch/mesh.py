@@ -11,8 +11,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.dist import compat
-
 # TPU v5e hardware constants (per chip) used by the roofline analysis.
 PEAK_FLOPS_BF16 = 197e12          # FLOP/s
 HBM_BW = 819e9                    # bytes/s
@@ -20,7 +18,8 @@ ICI_BW_PER_LINK = 50e9            # bytes/s per link
 
 
 def _mk(shape, axes):
-    return compat.make_mesh(shape, axes)
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -13,6 +13,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import os
 import time
@@ -25,7 +26,6 @@ from repro.configs import get_config, get_reduced_config
 from repro.configs.base import InputShape
 from repro.core.consensus import ConsensusConfig
 from repro.data.synthetic import token_batch
-from repro.dist import compat
 from repro.launch import mesh as mesh_lib
 from repro.train import steps as steps_lib
 
@@ -88,7 +88,7 @@ def main(argv=None):
             start = s
             print(f"resumed from step {start}")
 
-    ctx = compat.set_mesh(mesh)
+    ctx = contextlib.nullcontext() if mesh is None else jax.set_mesh(mesh)
     data_key = jax.random.key(args.seed + 1)
     t0 = time.time()
     with ctx:

@@ -374,7 +374,8 @@ class Fabric:
         """
         if self.mode == "buffer":
             # repro: noqa[raw-einsum-in-plan] — deliberate: must be the EXACT expression of core._default_nbr_reduce (the bitwise-identity keystone); tests pin async == sync
-            return jnp.einsum("vu,utd->vtd", self.adjf, st.mailbox)
+            return jnp.einsum("vu,utd->vtd", self.adjf, st.mailbox,
+                              precision="highest")
         if self.stale_limit is not None:
             fresh = (st.silence <= self.stale_limit).astype(jnp.float32)
             return jnp.sum((self.adjf * fresh)[:, :, None, None]

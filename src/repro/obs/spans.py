@@ -42,10 +42,7 @@ import time
 from contextlib import contextmanager
 from typing import Iterator, List, Optional
 
-try:                                    # optional: jax timeline overlay
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:                       # pragma: no cover - old jax
-    _TraceAnnotation = None
+from jax.profiler import TraceAnnotation
 
 #: recorder capacity: beyond this many events new spans are counted
 #: (``dropped_spans``) but not stored, so a long-lived serve process
@@ -70,16 +67,10 @@ def span(name: str, **attrs) -> Iterator[None]:
     """
     global _DROPPED
     t0 = time.perf_counter_ns()
-    if _TraceAnnotation is not None:
-        ctx = _TraceAnnotation(name)
-        ctx.__enter__()
-    else:                               # pragma: no cover - old jax
-        ctx = None
     try:
-        yield
+        with TraceAnnotation(name):
+            yield
     finally:
-        if ctx is not None:
-            ctx.__exit__(None, None, None)
         dur = time.perf_counter_ns() - t0
         ev = {
             "name": name,

@@ -88,7 +88,7 @@ class PredictModel(NamedTuple):
         X = jnp.asarray(X, jnp.float32)
         if X.ndim == 3:
             X = jnp.broadcast_to(X[None], (V,) + X.shape)
-        return (jnp.einsum("vtnp,vtp->vtn", X, self.W)
+        return (jnp.einsum("vtnp,vtp->vtn", X, self.W, precision="highest")
                 + self.b[..., None])
 
     def predict(self, X) -> jnp.ndarray:
@@ -127,4 +127,4 @@ def gemm_rows(Wf: jnp.ndarray, bf: jnp.ndarray,
     (B, V*T).  Jitted once per (B, p, V*T) bucket shape; runs on
     whatever device its (committed) inputs live on, which is how the
     server pins batches to devices."""
-    return X @ Wf.T + bf[None, :]
+    return jnp.matmul(X, Wf.T, precision="highest") + bf[None, :]

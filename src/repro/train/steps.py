@@ -21,7 +21,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import InputShape, ModelConfig
 from repro.core import consensus as consensus_lib
-from repro.dist import compat
 from repro.models import model as model_lib
 from repro.models import transformer
 from repro.optim import adamw, apply_updates, clip_by_global_norm
@@ -202,7 +201,7 @@ def make_consensus_train_step(cfg: ModelConfig, mesh: Mesh,
                                       dual=P(axis), step=P())
         metric_spec = {"loss": P(), "grad_norm": P(),
                        "consensus_gap": P()}
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             local_step, mesh=mesh,
             in_specs=(st_spec, batch_spec),
             out_specs=(st_spec, metric_spec),

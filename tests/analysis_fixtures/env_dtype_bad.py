@@ -1,5 +1,5 @@
-"""BAD: env-dependent-dtype — the x64 switch touched outside
-dist.compat makes numeric results depend on ambient process config."""
+"""BAD: env-dependent-dtype — touching the x64 switch makes numeric
+results depend on ambient process config."""
 import jax
 
 

@@ -1,7 +1,7 @@
-"""GOOD: env-keyed dtype behavior goes through the blessed
-dist.compat shim (the only module allowed to read the switch)."""
-from repro.dist import compat
+"""GOOD: the dtype is pinned on the leaf, so the result does not depend
+on the ambient x64 switch."""
+import jax.numpy as jnp
 
 
-def wants_x64():
-    return compat.jnp_float_bits() == 64
+def as_f32(x):
+    return jnp.asarray(x, jnp.float32)

@@ -10,13 +10,15 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 420) -> str:
-    """Run a python snippet in a subprocess with N forced host devices.
+    """Run a python snippet in a CPU subprocess with N forced host devices.
 
     Needed because the main pytest process must keep the default single
     device (per the dry-run isolation rule) while distributed tests need a
-    multi-device mesh.
+    multi-device mesh.  The child is pinned to the CPU: a chip belongs to
+    one process, and the parent may hold it.
     """
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run(

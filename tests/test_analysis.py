@@ -148,7 +148,7 @@ def test_rule_path_scoping():
     assert not scan.applies("analysis/rules.py")       # tooling
     env = rules.get_rule("env-dependent-dtype")
     assert env.applies("serve/model.py")
-    assert not env.applies("dist/compat.py")           # the blessed shim
+    assert not env.applies("analysis/rules.py")        # tooling
     down = rules.get_rule("silent-downcast")
     assert down.applies("store/session_store.py")
     tel = rules.get_rule("telemetry-read-in-kernel")
@@ -187,6 +187,22 @@ def test_audit_fn_flags_denied_dtype_and_prim():
     fs = audit_fn(scatter_add, jnp.ones((4,), jnp.float32))
     assert any(f.rule == "jaxpr-denied-prim"
                and "scatter-add" in f.message for f in fs)
+
+
+@pytest.mark.parametrize("precision,flagged", [
+    (None, True), ("high", True), ("highest", False)])
+def test_audit_fn_flags_default_precision_f32_dot(precision, flagged):
+    """An f32 contraction below 'highest' runs as bf16 passes on a TPU:
+    the audit flags it, through a scan body too."""
+    import jax
+    import jax.numpy as jnp
+
+    def matvec_scan(K, x):
+        body = lambda c, _: (jnp.matmul(K, c, precision=precision), None)
+        return jax.lax.scan(body, x, None, length=2)[0]
+
+    fs = audit_fn(matvec_scan, jnp.eye(4), jnp.ones((4,)))
+    assert any(f.rule == "jaxpr-default-precision" for f in fs) == flagged
 
 
 def test_entry_points_are_clean():

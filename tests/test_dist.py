@@ -29,38 +29,24 @@ def test_dtsvm_dist_matches_reference(topology):
     assert "MATCH" in out
 
 
-# This test used to be the suite's one xfail: the consensus train step
-# runs shard_map with axis_names={"data"} so the model axis stays AUTO
-# (GSPMD), and that partial-auto combination trips an XLA SPMD
-# partitioner check on jax 0.4.x whenever the model axis is >1.  Rather
-# than xfail the whole property, the mesh adapts: jax >= 0.5 covers the
-# full partial-auto (data=4, model=2) layout, jax 0.4.x runs the same
-# consensus dynamics with model=1 (all axes effectively manual — no
-# partial-auto partitioning to trip).  The assertions are identical; the
-# model>1 layout is exercised by CI's nightly full lane, which installs
-# jax-latest and includes the slow tests.  See API.md "Known test-suite
-# caveats".
-_MODEL_AXIS = 2 if tuple(map(
-    int, __import__("jax").__version__.split(".")[:2])) >= (0, 5) else 1
-
-
 @pytest.mark.slow
 def test_consensus_trainer_agrees_and_learns():
     """ADMM-consensus training on a ring: loss decreases AND replicas
     converge toward consensus (gap shrinks) — the deep-net lift of the
-    paper's Prop.-1 dynamics."""
-    out = run_with_devices(f"""
+    paper's Prop.-1 dynamics.  The consensus step runs shard_map with
+    axis_names={"data"}, so the model axis stays auto (partial-auto
+    partitioning over data=4, model=2)."""
+    out = run_with_devices("""
         import jax, jax.numpy as jnp
         from repro.configs import get_reduced_config
         from repro.configs.base import InputShape
         from repro.core.consensus import ConsensusConfig
-        from repro.dist import compat
         from repro.launch import mesh as mesh_lib
         from repro.train import steps as steps_lib
         from repro.data.synthetic import token_batch
 
         cfg = get_reduced_config("qwen2-0.5b")
-        mesh = mesh_lib.make_debug_mesh(data=4, model={_MODEL_AXIS})
+        mesh = mesh_lib.make_debug_mesh(data=4, model=2)
         shape = InputShape("t", 64, 8, "train")
         rng = jax.random.key(0)
         state = steps_lib.make_consensus_train_state(cfg, rng, mesh, shape,
@@ -73,7 +59,7 @@ def test_consensus_trainer_agrees_and_learns():
         step = steps_lib.make_consensus_train_step(
             cfg, mesh, ConsensusConfig(eta=0.1, every=1), lr=3e-3)
         batch = token_batch(jax.random.key(2), cfg.vocab_size, 8, 64)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             losses, gaps = [], []
             for i in range(10):
                 state, m = step(state, batch)
@@ -93,7 +79,6 @@ def test_consensus_every_k_skips_exchange():
         from repro.configs import get_reduced_config
         from repro.configs.base import InputShape
         from repro.core.consensus import ConsensusConfig
-        from repro.dist import compat
         from repro.launch import mesh as mesh_lib
         from repro.train import steps as steps_lib
         from repro.data.synthetic import token_batch
@@ -106,7 +91,7 @@ def test_consensus_every_k_skips_exchange():
         step = steps_lib.make_consensus_train_step(
             cfg, mesh, ConsensusConfig(eta=0.1, every=4), lr=1e-3)
         batch = token_batch(jax.random.key(2), cfg.vocab_size, 4, 32)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             for i in range(3):
                 state, m = step(state, batch)
         assert int(state.step) == 3
@@ -158,7 +143,6 @@ def test_allreduce_train_step_sharded():
         import jax, jax.numpy as jnp
         from repro.configs import get_reduced_config
         from repro.configs.base import InputShape
-        from repro.dist import compat
         from repro.dist import sharding as shp
         from repro.launch import mesh as mesh_lib
         from repro.train import steps as steps_lib
@@ -168,7 +152,7 @@ def test_allreduce_train_step_sharded():
         mesh = mesh_lib.make_debug_mesh(data=2, model=2)
         shape = InputShape("t", 64, 4, "train")
         rng = jax.random.key(0)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             state = steps_lib.make_train_state(cfg, rng, shape)
             spec = shp.param_specs(
                 jax.eval_shape(lambda: state), mesh, shp.ctx_for(cfg))

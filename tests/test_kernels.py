@@ -243,3 +243,45 @@ def test_ops_qp_multi_gamma_unbatched():
     assert out_vec.shape == out_scalar.shape == lam0.shape
     np.testing.assert_array_equal(np.asarray(out_vec),
                                   np.asarray(out_scalar))
+
+
+# ---------------------------------------------------------------------------
+# dispatch switches: no hidden oracle or interpreter on a TPU
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend,interpret,want", [
+    ("cpu", None, True), ("cpu", True, True), ("cpu", False, False),
+    ("tpu", None, False), ("tpu", False, False), ("tpu", True, ValueError),
+])
+def test_default_interpret_per_platform(monkeypatch, backend, interpret,
+                                        want):
+    from repro.kernels.launch import default_interpret
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if want is ValueError:
+        with pytest.raises(ValueError, match="interpret=True on a TPU"):
+            default_interpret(interpret)
+    else:
+        assert default_interpret(interpret) is want
+
+
+@pytest.mark.parametrize("backend,flag,want", [
+    ("cpu", None, False), ("cpu", "0", False), ("cpu", "1", True),
+    ("tpu", None, True), ("tpu", "1", True), ("tpu", "0", RuntimeError),
+])
+def test_use_pallas_switch_per_platform(monkeypatch, backend, flag, want):
+    """REPRO_USE_PALLAS steers the CPU tests only; on a TPU the kernels
+    run unless the explicit reference scope is open."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if flag is None:
+        monkeypatch.delenv("REPRO_USE_PALLAS", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_USE_PALLAS", flag)
+    with ops.reference_kernels():
+        assert ops._use_pallas() is False
+    if want is RuntimeError:
+        with pytest.raises(RuntimeError, match="REPRO_USE_PALLAS=0"):
+            ops._use_pallas()
+    else:
+        assert ops._use_pallas() is want

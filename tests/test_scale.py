@@ -60,14 +60,26 @@ def test_ref_gram_rows_is_row_slice_of_dense(n, m, d):
 def test_tiled_pallas_kernel_bitwise_vs_square_kernel(tile, n):
     """Interpret mode: every (tile_m, tile_n) grid reproduces the square
     DEFAULT_BLOCK kernel bit for bit (the contraction order over the
-    padded feature dim is tile-independent)."""
+    padded feature dim is tile-independent).
+
+    Ragged ``n`` (not a multiple of 8) is the exception: the square
+    kernel's block is then n rounded up to 8 (104 for n=100), the tiled
+    kernel's the tile, and XLA's CPU dot under jax 0.9 rounds those two
+    padded output shapes differently.  There the tiles are held to the
+    f32 dot-product error bound instead, D * eps * (|Z| a |Z|^T)."""
     rng = np.random.default_rng(1)
     Z = jnp.asarray(rng.normal(size=(n, 11)).astype(np.float32))
     a = jnp.asarray(rng.uniform(0.1, 2.0, size=(11,)).astype(np.float32))
-    dense = gram_kernel.weighted_gram_2d(Z, a, interpret=True)
-    tiled = gram_kernel.weighted_gram_tiled(Z, a, Z, tile=tile,
-                                            interpret=True)
-    np.testing.assert_array_equal(np.asarray(tiled), np.asarray(dense))
+    dense = np.asarray(gram_kernel.weighted_gram_2d(Z, a, interpret=True))
+    tiled = np.asarray(gram_kernel.weighted_gram_tiled(Z, a, Z, tile=tile,
+                                                       interpret=True))
+    if n % 8 == 0:
+        np.testing.assert_array_equal(tiled, dense)
+    else:
+        absZ = np.abs(np.asarray(Z, np.float64))
+        bound = Z.shape[1] * np.finfo(np.float32).eps * (
+            (absZ * np.asarray(a, np.float64)) @ absZ.T)
+        assert np.all(np.abs(tiled - dense) <= bound)
 
 
 def test_tiled_pallas_row_panels_bitwise(monkeypatch):
