@@ -54,6 +54,7 @@ from repro.core import dtsvm as core
 from repro.core import dtsvm_dist
 from repro.engine import plan as engine_plan
 from repro.net import async_admm
+from repro.obs import spans as obs_spans
 from repro.obs import telemetry as obs_telemetry
 
 _REGISTRY: Dict[str, Callable] = {}
@@ -263,7 +264,10 @@ def _qp_rows(K_rows, q_rows, hi_rows, lam0_rows, L, *, iters: int,
     step = 1.0 / L                                        # (V, T)
     matvec = jax.vmap(jax.vmap(                          # rows of K @ y
         lambda Kr, yf: jnp.matmul(Kr, yf, precision="highest")))
-    gather = lambda y: jax.lax.all_gather(y, axis, axis=2, tiled=True)
+    def gather(y):
+        with obs_spans.scope("neighbour_exchange"):
+            return jax.lax.all_gather(y, axis, axis=2, tiled=True)
+
     lam = jnp.clip(lam0_rows, 0.0, hi_rows)
 
     if qp_solver == "pg":
@@ -365,20 +369,26 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
                        check_vma=False)
     def run_shard(st, pr):
         # -- invariants: counts/u/a replicated, Z/hi/K as row panels --
-        ntp, nbr, u, a, hi_rows = inv_lib._masks_part(pr)
-        Z_rows = inv_lib.compute_z(pr)                    # (V,T,Nl,p+1)
-        Z_full = jax.lax.all_gather(Z_rows, axis, axis=2, tiled=True)
-        Nl = Z_rows.shape[2]
-        chunk = None if budget is None else \
-            budget.row_chunk(V * T, Nl, cols=N)
-        if chunk is None:
-            K_rows = kops.weighted_gram_rows(Z_rows, a, Z_full, tile=tile)
-            rs = jnp.sum(jnp.abs(K_rows), axis=-1)
-        else:
-            K_rows, rs = inv_lib.streamed_gram_panel(Z_rows, a, Z_full,
-                                                     chunk, tile)
-        # global Gershgorin bound: max over ALL rows (max is exact)
-        L = jnp.maximum(jax.lax.pmax(jnp.max(rs, axis=-1), axis), 1e-12)
+        with obs_spans.scope("invariant_build"):
+            ntp, nbr, u, a, hi_rows = inv_lib._masks_part(pr)
+            Z_rows = inv_lib.compute_z(pr)                # (V,T,Nl,p+1)
+            with obs_spans.scope("neighbour_exchange"):
+                Z_full = jax.lax.all_gather(Z_rows, axis, axis=2,
+                                            tiled=True)
+            Nl = Z_rows.shape[2]
+            chunk = None if budget is None else \
+                budget.row_chunk(V * T, Nl, cols=N)
+            if chunk is None:
+                K_rows = kops.weighted_gram_rows(Z_rows, a, Z_full,
+                                                 tile=tile)
+                rs = jnp.sum(jnp.abs(K_rows), axis=-1)
+            else:
+                K_rows, rs = inv_lib.streamed_gram_panel(Z_rows, a, Z_full,
+                                                         chunk, tile)
+            # global Gershgorin bound: max over ALL rows (max is exact)
+            with obs_spans.scope("neighbour_exchange"):
+                rs_max = jax.lax.pmax(jnp.max(rs, axis=-1), axis)
+            L = jnp.maximum(rs_max, 1e-12)
         nbr_reduce = core._default_nbr_reduce(pr)
 
         def step(s):
@@ -387,28 +397,35 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
             g = f[..., : p + 1] / u[..., : p + 1] \
                 + f[..., p + 1:] / u[..., p + 1:]
             q_rows = pr.mask + jnp.sum(Z_rows * g[..., None, :], axis=-1)
-            lam = _qp_rows(K_rows, q_rows, hi_rows, s.lam, L,
-                           iters=qp_iters, axis=axis, qp_solver=qp_solver)
-            if reduce == "gather":
-                lam_full = jax.lax.all_gather(lam, axis, axis=2, tiled=True)
-                zl = jnp.einsum("vtn,vtnd->vtd", lam_full, Z_full,
-                                precision="highest")
-            else:
-                zl = jax.lax.psum(
-                    jnp.einsum("vtn,vtnd->vtd", lam, Z_rows,
-                               precision="highest"), axis)
+            with obs_spans.scope("qp_solve"):
+                lam = _qp_rows(K_rows, q_rows, hi_rows, s.lam, L,
+                               iters=qp_iters, axis=axis,
+                               qp_solver=qp_solver)
+                if reduce == "gather":
+                    with obs_spans.scope("neighbour_exchange"):
+                        lam_full = jax.lax.all_gather(lam, axis, axis=2,
+                                                      tiled=True)
+                    zl = jnp.einsum("vtn,vtnd->vtd", lam_full, Z_full,
+                                    precision="highest")
+                else:
+                    zl_rows = jnp.einsum("vtn,vtnd->vtd", lam, Z_rows,
+                                         precision="highest")
+                    with obs_spans.scope("neighbour_exchange"):
+                        zl = jax.lax.psum(zl_rows, axis)
             r_new, alpha, beta = engine_plan.consensus_update(
                 pr, s, u, ntp, nbr, f, zl, nbr_reduce)
             return core.DTSVMState(r=r_new, alpha=alpha, beta=beta, lam=lam)
 
         def body(s, _):
-            new = step(s)
-            out = eval_fn(new) if eval_fn is not None else jnp.float32(0)
-            # None is an empty pytree node: telemetry-off scans carry
-            # exactly the original outputs (bitwise contract)
-            tel = (None if telemetry is None
-                   else obs_telemetry.collect_shard_diagnostics(
-                       pr, hi_rows, new, s, telemetry.streams, axis))
+            with obs_spans.scope("scan_execute"):
+                new = step(s)
+                out = (eval_fn(new) if eval_fn is not None
+                       else jnp.float32(0))
+                # None is an empty pytree node: telemetry-off scans carry
+                # exactly the original outputs (bitwise contract)
+                tel = (None if telemetry is None
+                       else obs_telemetry.collect_shard_diagnostics(
+                           pr, hi_rows, new, s, telemetry.streams, axis))
             return new, (out, tel)
 
         return jax.lax.scan(body, st, None, length=iters)

@@ -52,6 +52,7 @@ import numpy as np
 
 from repro.core import qp as qp_lib
 from repro.kernels import ops as kops
+from repro.obs import spans as obs_spans
 
 _U_FLOOR = 1e-6
 
@@ -166,7 +167,8 @@ def _f_vec(prob: DTSVMProblem, state: DTSVMState, ntp, nbr, nbr_reduce):
     task_term = ntp[..., None] * r + task_sum * prob.couple[:, None, None]
     task_term = task_term.at[..., p + 1:].set(0.0)          # P0 projection
     # neighbor sums: sum over active neighbors of the same task
-    nbr_sum = nbr_reduce(r_act)
+    with obs_spans.scope("neighbour_exchange"):
+        nbr_sum = nbr_reduce(r_act)
     nbr_term = nbr[..., None] * r + nbr_sum
 
     pad = jnp.zeros((*alpha.shape[:-1], p + 1), alpha.dtype)
@@ -233,7 +235,8 @@ def dtsvm_step(state: DTSVMState, prob: DTSVMProblem,
     alpha = state.alpha + 0.5 * prob.eta1 * d_alpha[..., : p + 1] * act
 
     # eq. (9): beta update over active neighbors
-    nbr_sum = nbr_reduce(r_act)
+    with obs_spans.scope("neighbour_exchange"):
+        nbr_sum = nbr_reduce(r_act)
     d_beta = nbr[..., None] * r_new - nbr_sum
     beta = state.beta + 0.5 * prob.eta2 * d_beta * act
 

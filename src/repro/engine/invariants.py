@@ -143,6 +143,11 @@ def streamed_gram_panel(Zm: jnp.ndarray, a: jnp.ndarray, Zn: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("chunk", "tile", "_pallas"))
 def _streamed_gram_jit(Zm, a, Zn, *, chunk, tile, _pallas):
+    with obs_spans.scope("invariant_build"):
+        return _streamed_gram(Zm, a, Zn, chunk=chunk, tile=tile)
+
+
+def _streamed_gram(Zm, a, Zn, *, chunk, tile):
     batch = Zm.shape[:-2]
     M, D = Zm.shape[-2:]
     N = Zn.shape[-2]
@@ -180,6 +185,11 @@ def _streamed_rowsums_jit(Z, a, *, chunk, tile, _pallas):
     Each chunk runs the identical ``weighted_gram_rows`` + |.|-rowsum
     compute as ``_streamed_gram_jit``, so the resulting ``L`` is
     bitwise the streamed materialized build's at the same chunk."""
+    with obs_spans.scope("invariant_build"):
+        return _streamed_rowsums(Z, a, chunk=chunk, tile=tile)
+
+
+def _streamed_rowsums(Z, a, *, chunk, tile):
     batch = Z.shape[:-2]
     N, D = Z.shape[-2:]
     Zf = Z.reshape((-1, N, D))
@@ -257,8 +267,9 @@ def gram_and_lipschitz(Z: jnp.ndarray, a: jnp.ndarray,
             K, rs = streamed_gram_panel(Z, a, Z, chunk, budget.tile)
             return K, jnp.maximum(jnp.max(rs, axis=-1), 1e-12)
     tile = None if budget is None else budget.tile
-    K = kops.weighted_gram(Z, a, tile=tile)
-    return K, qp_lib.gershgorin_lipschitz(K)
+    with obs_spans.scope("invariant_build"):
+        K = kops.weighted_gram(Z, a, tile=tile)
+        return K, qp_lib.gershgorin_lipschitz(K)
 
 
 def compute_z(prob: core.DTSVMProblem) -> jnp.ndarray:

@@ -51,21 +51,24 @@ def consensus_update(prob: core.DTSVMProblem, state: core.DTSVMState,
     bitwise-identical across execution paths.
     """
     p = prob.X.shape[-1]
-    rhs = jnp.concatenate([zl, zl], axis=-1) - f               # [I,I]^T(..)-f
-    r_new = rhs / u                                            # eq. (7)
-    act = prob.active[..., None]
-    r_new = r_new * act + state.r * (1.0 - act)                # freeze
+    with obs_spans.scope("consensus_update"):
+        rhs = jnp.concatenate([zl, zl], axis=-1) - f           # [I,I]^T(..)-f
+        r_new = rhs / u                                        # eq. (7)
+        act = prob.active[..., None]
+        r_new = r_new * act + state.r * (1.0 - act)            # freeze
 
-    # eq. (8): alpha update on the (w0, b0) block, coupled nodes only
-    r_act = r_new * act
-    task_sum = jnp.sum(r_act, axis=1, keepdims=True) - r_act
-    d_alpha = (ntp[..., None] * r_new - task_sum * prob.couple[:, None, None])
-    alpha = state.alpha + 0.5 * prob.eta1 * d_alpha[..., : p + 1] * act
+        # eq. (8): alpha update on the (w0, b0) block, coupled nodes only
+        r_act = r_new * act
+        task_sum = jnp.sum(r_act, axis=1, keepdims=True) - r_act
+        d_alpha = (ntp[..., None] * r_new
+                   - task_sum * prob.couple[:, None, None])
+        alpha = state.alpha + 0.5 * prob.eta1 * d_alpha[..., : p + 1] * act
 
-    # eq. (9): beta update over active neighbors
-    nbr_sum = nbr_reduce(r_act)
-    d_beta = nbr[..., None] * r_new - nbr_sum
-    beta = state.beta + 0.5 * prob.eta2 * d_beta * act
+        # eq. (9): beta update over active neighbors
+        with obs_spans.scope("neighbour_exchange"):
+            nbr_sum = nbr_reduce(r_act)
+        d_beta = nbr[..., None] * r_new - nbr_sum
+        beta = state.beta + 0.5 * prob.eta2 * d_beta * act
     return r_new, alpha, beta
 
 
@@ -100,19 +103,23 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
     q = prob.mask + jnp.sum(Z * g[..., None, :], axis=-1)
 
     engine = qp_engines.get(qp_solver)
-    if qp_operator == "factored":
-        lam, zl = qp_engines.solve_factored_multi(
-            Z, inv.a, q, inv.hi, state.lam, iters=qp_iters,
-            L=inv.L)                                           # eq. (6)
-    elif getattr(engine, "supports_fold", False):
-        lam, zl = engine(inv.K, q, inv.hi, state.lam, iters=qp_iters,
-                         L=inv.L, precision=qp_precision, Z=Z)  # eq. (6)
-    else:
-        lam = engine(inv.K, q, inv.hi, state.lam,
-                     iters=qp_iters, L=inv.L)                  # eq. (6)
-        # repro: noqa[raw-einsum-in-plan] — deliberate: mul+reduce would materialize a (V,T,N,D) temporary; batching stability is pinned by the fig2-fig7 golden fixtures across all backends
-        zl = jnp.einsum("vtn,vtnd->vtd", lam, Z,
-                        precision="highest")                   # X^T Y lam
+    # the dual solve and its X^T Y lam summary (one launch in the fused
+    # engines): the qp_solve phase
+    with obs_spans.scope("qp_solve"):
+        if qp_operator == "factored":
+            lam, zl = qp_engines.solve_factored_multi(
+                Z, inv.a, q, inv.hi, state.lam, iters=qp_iters,
+                L=inv.L)                                       # eq. (6)
+        elif getattr(engine, "supports_fold", False):
+            lam, zl = engine(inv.K, q, inv.hi, state.lam, iters=qp_iters,
+                             L=inv.L, precision=qp_precision,
+                             Z=Z)                              # eq. (6)
+        else:
+            lam = engine(inv.K, q, inv.hi, state.lam,
+                         iters=qp_iters, L=inv.L)              # eq. (6)
+            # repro: noqa[raw-einsum-in-plan] — deliberate: mul+reduce would materialize a (V,T,N,D) temporary; batching stability is pinned by the fig2-fig7 golden fixtures across all backends
+            zl = jnp.einsum("vtn,vtnd->vtd", lam, Z,
+                            precision="highest")               # X^T Y lam
     r_new, alpha, beta = consensus_update(prob, state, u, ntp, nbr, f, zl,
                                           nbr_reduce)
     return core.DTSVMState(r=r_new, alpha=alpha, beta=beta, lam=lam)
@@ -180,8 +187,10 @@ class Plan:
             state = self.init_state()
         if telemetry is None:
             def body(st, _):
-                st = self.step(st)
-                out = eval_fn(st) if eval_fn is not None else jnp.float32(0)
+                with obs_spans.scope("scan_execute"):
+                    st = self.step(st)
+                    out = (eval_fn(st) if eval_fn is not None
+                           else jnp.float32(0))
                 return st, out
 
             with obs_spans.span("scan_execute", iters=int(iters)):
@@ -189,9 +198,10 @@ class Plan:
             return state, (hist if eval_fn is not None else None)
 
         def body(st, _):
-            new = self.step(st)
-            out = eval_fn(new) if eval_fn is not None else jnp.float32(0)
-            tel = telemetry.collect(self.prob, self.inv.hi, new, st)
+            with obs_spans.scope("scan_execute"):
+                new = self.step(st)
+                out = eval_fn(new) if eval_fn is not None else jnp.float32(0)
+                tel = telemetry.collect(self.prob, self.inv.hi, new, st)
             return new, (out, tel)
 
         with obs_spans.span("scan_execute", iters=int(iters),
@@ -325,12 +335,9 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
             raise ValueError("qp_operator='factored' is f32-only "
                              "(the low-rank matvec never streams K "
                              "tiles, so bf16 K has nothing to apply to)")
-    with obs_spans.span("plan_compile", qp_solver=qp_solver,
-                        qp_operator=qp_operator,
-                        budgeted=budget is not None):
-        inv = inv_lib.compute_invariants(
-            prob, nbr_counts=nbr_counts, budget=budget,
-            materialize_k=(qp_operator != "factored"))
-        return Plan(prob, inv, qp_iters=qp_iters, qp_solver=qp_solver,
-                    qp_precision=qp_precision, qp_operator=qp_operator,
-                    nbr_reduce=nbr_reduce, budget=budget)
+    inv = inv_lib.compute_invariants(
+        prob, nbr_counts=nbr_counts, budget=budget,
+        materialize_k=(qp_operator != "factored"))
+    return Plan(prob, inv, qp_iters=qp_iters, qp_solver=qp_solver,
+                qp_precision=qp_precision, qp_operator=qp_operator,
+                nbr_reduce=nbr_reduce, budget=budget)

@@ -5,7 +5,8 @@
 
     python -m repro.obs demo [--iters N] [--trace PATH] [--registry PATH]
         Fit a tiny synthetic problem with telemetry + spans enabled,
-        write the Chrome-trace JSON and the metrics-registry JSON (the
+        write the Chrome-trace JSON and the metrics-registry JSON (with
+        its telemetry, spans and compiles sections; the
         artifacts the CI obs lane uploads), and print the report.
 """
 from __future__ import annotations
@@ -43,6 +44,7 @@ def _cmd_demo(args) -> int:
                                 adj=graph.ring(V))
     reg = MetricsRegistry.from_solver(solver)
     reg.record_spans()
+    reg.record_compiles()
     reg.save(args.registry)
     obs.save_trace(args.trace)
     print(f"wrote {args.trace} ({len(obs.iter_spans())} spans) and "

@@ -34,6 +34,9 @@ section        payload
                (first/last/min/max per stream), not the raw arrays
 ``spans``      per-name span count + total duration (ms) from the span
                recorder
+``compiles``   ``obs.compiles.compile_counts``: traces, lowerings and
+               backend compiles (count + ms) per program and per
+               enclosing span
 =============  =========================================================
 """
 from __future__ import annotations
@@ -43,6 +46,7 @@ from typing import Any, Dict, List, Optional
 
 import numpy as np
 
+from repro.obs import compiles as compiles_lib
 from repro.obs import spans as spans_lib
 from repro.obs import telemetry as telemetry_lib
 
@@ -97,6 +101,15 @@ class MetricsRegistry:
             row["count"] += 1
             row["total_ms"] += float(ev.get("dur", 0.0)) / 1e3
         return self.record("spans", agg)
+
+    def record_compiles(self, counts: Optional[dict] = None
+                        ) -> "MetricsRegistry":
+        """A ``compiles`` section: per program and per enclosing span,
+        the count and milliseconds of each compile phase (``trace`` /
+        ``lower`` / ``compile``) — ``obs.compile_counts()`` unless
+        ``counts`` is given."""
+        return self.record("compiles", compiles_lib.compile_counts()
+                           if counts is None else counts)
 
     @classmethod
     def from_session(cls, sess) -> "MetricsRegistry":

@@ -1,34 +1,66 @@
-"""Host-side span tracing: phase timings as Chrome-trace events.
+"""Phase names on both sides: host spans and device scopes.
 
 A *span* wraps one real phase boundary of the system — invariant/K
-builds, plan compiles, scan executions, session replans, store
-snapshot/restore, serve batch windows — and records a wall-clock
-``(name, start, duration)`` triple into a process-wide buffer.  The
-buffer exports as Chrome trace-event JSON (``to_chrome_trace`` /
-``save_trace``), so ``chrome://tracing`` and Perfetto open it directly.
+builds, scan dispatches, session replans, store snapshot/restore, serve
+batch windows — and records a wall-clock ``(name, start, duration)``
+triple into a process-wide buffer.  The buffer exports as Chrome
+trace-event JSON (``to_chrome_trace`` / ``save_trace``), so
+``chrome://tracing`` and Perfetto open it directly.
 
-The recorder is deliberately dumb and cheap: ``perf_counter_ns`` on
+The same name labels the device side: ``span`` also opens a
+``jax.named_scope`` of its name, and :func:`scope` opens the scope alone.
+Every operation traced inside a scope carries its name in the HLO
+``op_name`` metadata of the compiled program (``as_text()``, IR dumps),
+so each instruction's phase is known.  (A TPU profile, as
+``jax.profiler.ProfileData`` reads it, names each device op by its HLO
+text without that metadata.)  Inside traced code (a scan body, a
+jitted build, a shard body) use :func:`scope` only: a host span there
+would time the trace, not the work.  A scope outside any trace labels
+nothing — an eagerly dispatched op is lowered as its own program, under
+a fresh name stack.  Scopes are metadata: the lowered instructions and
+every output are unchanged.
+
+The recorder is deliberately dumb and cheap: ``time.time_ns`` on
 enter/exit, one lock-protected list append, no allocation in the body.
-Spans NEVER touch device values — they time host phases only, so
-wrapping a traced region times the *trace*, not the execution (the
-execution is timed by wrapping the blocking call, e.g. ``Plan.run``).
-When a ``jax.profiler`` trace is active, each span additionally emits a
-``TraceAnnotation`` so the phases line up inside the XLA timeline.
+Spans NEVER touch device values — they time host phases only.  When a
+``jax.profiler`` trace is active, each span also emits a
+``TraceAnnotation`` of its name.  The recorder's clock is the one the
+profiler stamps its host events with (the host's realtime clock): an
+event's ``ts`` is microseconds since the epoch, and a profile's event
+times are the same clock less the session's ``profile_start_time`` (a
+stat of its ``Task Environment`` plane), so a recorded span and its
+annotation start at the same instant.  ``repro.obs.compiles`` writes
+JAX's trace / lower / compile phases into the same recorder as
+``compile:<program>`` spans.
 
-Span taxonomy (the names the instrumented call sites use):
+Taxonomy (the names the instrumented call sites use):
 
-===================  ====================================================
-name                 phase
-===================  ====================================================
-``invariant_build``  ``engine.invariants.compute_invariants`` (the K
-                     build, dense or budgeted)
-``plan_compile``     ``engine.compile_problem`` (validation + build)
-``plan_replan``      ``Plan.replan`` (incremental invariant rebuild)
-``scan_execute``     ``Plan.run``'s ADMM scan (trace + dispatch)
-``store_snapshot``   ``store.snapshot_session``
-``store_restore``    ``store.restore_session``
-``serve_batch``      one ``PredictServer`` padded-bucket GEMM batch
-===================  ====================================================
+======================  =================================================
+name                    phase
+======================  =================================================
+``invariant_build``     span: ``engine.invariants.compute_invariants``
+                        (the K build, dense or budgeted); scope: the
+                        bodies of the jitted streamed builds and the
+                        dense Gram/Lipschitz pair
+``plan_replan``         span: ``Plan.replan`` (incremental rebuild)
+``scan_execute``        span: ``Plan.run``'s ADMM scan call, which times
+                        the scan's trace, lowering, compile and dispatch
+                        — not its execution, which is asynchronous;
+                        scope: the scan body, whose device ops are the
+                        execution
+``qp_solve``            scope: the dual box-QP engine call of
+                        ``plan_step`` and the row-panel QP of the
+                        ``sample_shard`` backend
+``consensus_update``    scope: eqs. (7)-(9), ``plan.consensus_update``
+``neighbour_exchange``  scope: every neighbour sum (the ``nbr_reduce``
+                        call sites) and the collectives of the sharded
+                        backends
+``store_snapshot``      span: ``store.snapshot_session``
+``store_restore``       span: ``store.restore_session``
+``serve_batch``         span: one ``PredictServer`` padded-bucket batch
+``compile:<program>``   recorder only: one trace, lowering or backend
+                        compile of ``<program>`` (``repro.obs.compiles``)
+======================  =================================================
 
 Callers may add their own names freely — the taxonomy is a convention,
 not a schema.
@@ -42,6 +74,7 @@ import time
 from contextlib import contextmanager
 from typing import Iterator, List, Optional
 
+import jax
 from jax.profiler import TraceAnnotation
 
 #: recorder capacity: beyond this many events new spans are counted
@@ -52,12 +85,54 @@ MAX_EVENTS = 100_000
 _LOCK = threading.Lock()
 _EVENTS: List[dict] = []
 _DROPPED = 0
-_T0_NS = time.perf_counter_ns()
+_OPEN = threading.local()          # per thread: names of the open spans
+
+
+def scope(name: str):
+    """The device side of phase ``name``: a ``jax.named_scope``, so that
+    every op traced inside it carries ``name`` in its ``op_name``."""
+    return jax.named_scope(name)
+
+
+def _open_spans() -> List[str]:
+    names = getattr(_OPEN, "names", None)
+    if names is None:
+        names = _OPEN.names = []
+    return names
+
+
+def current_span() -> Optional[str]:
+    """The innermost span open on the calling thread, or None."""
+    names = _open_spans()
+    return names[-1] if names else None
+
+
+def record(name: str, start_ns: int, dur_ns: int,
+           attrs: Optional[dict] = None) -> None:
+    """Append one complete event stamped on the recorder's clock
+    (``time.time_ns``) — what :func:`span` does on exit."""
+    global _DROPPED
+    ev = {
+        "name": name,
+        "ph": "X",
+        "ts": start_ns / 1e3,               # microseconds since the epoch
+        "dur": max(dur_ns, 0) / 1e3,
+        "pid": os.getpid(),
+        "tid": threading.get_ident(),
+    }
+    if attrs:
+        ev["args"] = attrs
+    with _LOCK:
+        if len(_EVENTS) < MAX_EVENTS:
+            _EVENTS.append(ev)
+        else:
+            _DROPPED += 1
 
 
 @contextmanager
 def span(name: str, **attrs) -> Iterator[None]:
-    """Record one host-side phase as a Chrome-trace complete event.
+    """Record one host-side phase as a Chrome-trace complete event, and
+    open the phase's device :func:`scope` around it.
 
     ``attrs`` (plain JSON-able values) land in the event's ``args`` and
     show up in the trace viewer's detail pane::
@@ -65,28 +140,15 @@ def span(name: str, **attrs) -> Iterator[None]:
         with obs.span("scan_execute", iters=30):
             state, hist = plan.run(state, iters=30)
     """
-    global _DROPPED
-    t0 = time.perf_counter_ns()
+    names = _open_spans()
+    names.append(name)
+    t0 = time.time_ns()
     try:
-        with TraceAnnotation(name):
+        with TraceAnnotation(name), scope(name):
             yield
     finally:
-        dur = time.perf_counter_ns() - t0
-        ev = {
-            "name": name,
-            "ph": "X",
-            "ts": (t0 - _T0_NS) / 1e3,          # microseconds
-            "dur": dur / 1e3,
-            "pid": os.getpid(),
-            "tid": threading.get_ident(),
-        }
-        if attrs:
-            ev["args"] = attrs
-        with _LOCK:
-            if len(_EVENTS) < MAX_EVENTS:
-                _EVENTS.append(ev)
-            else:
-                _DROPPED += 1
+        names.pop()
+        record(name, t0, time.time_ns() - t0, attrs)
 
 
 def iter_spans() -> List[dict]:

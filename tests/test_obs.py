@@ -2,10 +2,14 @@
 telemetry-off; telemetry adds zero retraces), the stream catalog across
 every backend, span tracing + Chrome-trace export, the metrics
 registry, durable-session carriage of telemetry, and the CLI."""
+import contextlib
+import glob
 import json
 import os
+import re
 import subprocess
 import sys
+import time
 
 import jax
 import numpy as np
@@ -18,6 +22,7 @@ from repro.api.solvers import SolverConfig
 from repro.core import graph
 from repro.data import synthetic
 from repro.net.policies import NetConfig
+from repro.obs import spans as spans_lib
 from repro.obs import telemetry as telemetry_lib
 
 from helpers import run_with_devices
@@ -295,8 +300,7 @@ def test_spans_cover_phase_boundaries(tmp_path):
     with obs.span("fit", tag="test"):
         api.DTSVM(iters=2, qp_iters=4).fit(X, y, mask, adj)
     names = [e["name"] for e in obs.iter_spans()]
-    for expected in ("invariant_build", "plan_compile", "scan_execute",
-                     "fit"):
+    for expected in ("invariant_build", "scan_execute", "fit"):
         assert expected in names, names
     # nesting: the wrapping span closes last, so it is recorded last
     assert names[-1] == "fit"
@@ -354,6 +358,196 @@ def test_store_and_serve_phases_emit_spans(tmp_path):
         srv.close()
     names = {e["name"] for e in obs.iter_spans()}
     assert {"store_snapshot", "store_restore", "serve_batch"} <= names
+
+
+# ---------------------------------------------------------------------------
+# device scopes: the span names in the lowered programs' op metadata
+# ---------------------------------------------------------------------------
+PHASES = ("scan_execute", "qp_solve", "consensus_update",
+          "neighbour_exchange")
+
+
+@contextlib.contextmanager
+def _ir_dump(tmp_path):
+    """Dump every module lowered in the block; yields ``texts(program)``
+    -> the dumped module texts of that program (``jit_scan``, ...)."""
+    d = str(tmp_path / "ir")
+    jax.config.update("jax_dump_ir_to", d)
+    try:
+        yield lambda prog: [open(f).read() for f in sorted(glob.glob(
+            os.path.join(d, f"*_{prog}_compile.mlir")))]
+    finally:
+        jax.config.update("jax_dump_ir_to",
+                          os.environ.get("JAX_DUMP_IR_TO", ""))
+
+
+def _scoped(text, name):
+    """``name`` is a scope of some op: it stands in a name-stack
+    location path (a function's own name location has no '/')."""
+    return re.search(r'loc\("[^"]*\b' + name + '/', text) is not None
+
+
+def _strip_locations(text):
+    lines = [ln for ln in text.splitlines() if not ln.startswith("#loc")]
+    return "\n".join(re.sub(r" loc\(#loc\d*\)", "", ln) for ln in lines)
+
+
+def _instructions(text):
+    return sum(1 for ln in text.splitlines() if "stablehlo." in ln)
+
+
+FITS = {
+    "vmap_scan": (dict(iters=2, qp_iters=4), "jit_scan", PHASES),
+    "budgeted_build": (dict(iters=2, qp_iters=4,
+                            budget=api.PlanBudget(max_elems=64)),
+                       "jit__streamed_gram_jit", ("invariant_build",)),
+    "factored_build": (dict(iters=2, qp_iters=4,
+                            qp_solver="pallas_fused_multi",
+                            qp_operator="factored"),
+                       "jit__streamed_rowsums_jit", ("invariant_build",)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FITS))
+def test_scopes_name_each_phase_in_the_lowered_program(case, tmp_path):
+    kw, program, phases = FITS[case]
+    X, y, mask, adj = _data()
+    jax.clear_caches()
+    with _ir_dump(tmp_path) as texts:
+        api.DTSVM(SolverConfig(**kw)).fit(X, y, mask, adj)
+        mods = texts(program)
+    assert len(mods) == 1, program
+    for name in phases:
+        assert _scoped(mods[0], name), (program, name)
+
+
+@pytest.mark.parametrize("case", ["vmap_scan", "budgeted_build"])
+def test_scopes_change_no_instruction_and_no_output(case, tmp_path,
+                                                    monkeypatch):
+    kw, program, phases = FITS[case]
+    X, y, mask, adj = _data()
+    jax.clear_caches()          # the jitted build lowers again each time
+    with _ir_dump(tmp_path / "on") as texts:
+        on = api.DTSVM(SolverConfig(**kw)).fit(X, y, mask, adj)
+        with_scopes = texts(program)[0]
+    monkeypatch.setattr(spans_lib, "scope",
+                        lambda name: contextlib.nullcontext())
+    jax.clear_caches()
+    with _ir_dump(tmp_path / "off") as texts:
+        off = api.DTSVM(SolverConfig(**kw)).fit(X, y, mask, adj)
+        without = texts(program)[0]
+    assert not _scoped(without, phases[0])
+    assert _instructions(with_scopes) == _instructions(without)
+    assert _strip_locations(with_scopes) == _strip_locations(without)
+    assert _bitwise(on.state_, off.state_)
+
+
+@pytest.mark.slow
+def test_sample_shard_scopes_on_four_devices():
+    run_with_devices(r"""
+        import glob, os, re, tempfile
+        import numpy as np, jax
+        from repro import api
+        from repro.api.solvers import SolverConfig
+        from repro.core import graph
+        from repro.data import synthetic
+
+        data = synthetic.make_multitask_data(
+            V=3, T=2, p=6, n_train=np.full((3, 2), 16, int), n_test=8,
+            relatedness=0.9, seed=0)
+        adj = graph.make_graph("ring", 3, seed=0)
+        d = tempfile.mkdtemp()
+        jax.config.update("jax_dump_ir_to", d)
+        api.DTSVM(SolverConfig(iters=2, qp_iters=4, backend="sample_shard",
+                               backend_options={"n_shards": 4})).fit(
+            data["X"], data["y"], data["mask"], adj)
+        (path,) = glob.glob(os.path.join(d, "*_jit_run_shard_compile.mlir"))
+        text = open(path).read()
+        for name in ("invariant_build", "scan_execute", "qp_solve",
+                     "consensus_update", "neighbour_exchange"):
+            assert re.search(r'loc\("[^"]*\b' + name + '/', text), name
+        # every collective of the fit is named as the exchange
+        coll = re.findall(r'loc\("([^"]*/(?:all_gather|pmax|psum))"', text)
+        assert coll
+        assert all("neighbour_exchange/" in c for c in coll), coll
+        print("OK")
+        """, n_devices=4)
+
+
+# ---------------------------------------------------------------------------
+# the compile counter and the shared clock
+# ---------------------------------------------------------------------------
+def _toy_fn():
+    def _toy(x):
+        return x * 2.0 + 1.0
+    return _toy
+
+
+def test_compile_counter_counts_relowering_per_program_and_span():
+    x = jax.numpy.ones(3)
+    cached = jax.jit(_toy_fn())
+    cached(x)
+    obs.clear_compiles()
+    obs.clear_spans()
+    n = 3
+    with obs.span("relower"):
+        for _ in range(n):
+            # a new function each call, as a new closure is: re-lowered
+            jax.jit(_toy_fn())(x)
+    with obs.span("cached"):
+        for _ in range(n):
+            cached(x)                 # found in jit's cache: nothing
+    counts = obs.compile_counts()
+    prog = counts["programs"]["jit(_toy)"]
+    assert prog["lower"]["count"] == n and prog["compile"]["count"] == n
+    assert prog["trace"]["count"] == n and prog["lower"]["ms"] > 0
+    assert counts["spans"]["relower"]["lower"]["count"] == n
+    assert "cached" not in counts["spans"]
+    recorded = [e for e in obs.iter_spans()
+                if e["name"] == "compile:jit(_toy)"]
+    assert len(recorded) == 3 * n
+    assert {e["args"]["span"] for e in recorded} == {"relower"}
+    assert {e["args"]["phase"] for e in recorded} == {"trace", "lower",
+                                                       "compile"}
+    reg = obs.MetricsRegistry().record_compiles()
+    assert reg.get("compiles")["programs"]["jit(_toy)"]["lower"][
+        "count"] == n
+    obs.clear_compiles()
+    assert obs.compile_counts() == {"programs": {}, "spans": {}}
+
+
+def test_recorder_and_profiler_share_the_clock(tmp_path):
+    from jax.profiler import ProfileData
+
+    obs.clear_spans()
+    d = str(tmp_path / "prof")
+    jax.profiler.start_trace(d)
+    try:
+        with obs.span("clock_probe"):
+            time.sleep(0.005)
+        jax.jit(lambda v: v - 3.0)(jax.numpy.ones(2))
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(d, "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    start = None
+    annotations = {}
+    for plane in pd.planes:
+        st = dict(plane.stats)
+        if "profile_start_time" in st:
+            start = int(st["profile_start_time"])
+        for line in plane.lines:
+            for ev in line.events:
+                annotations.setdefault(ev.name, (ev.start_ns, ev.end_ns))
+    assert start is not None
+    ev = [e for e in obs.iter_spans() if e["name"] == "clock_probe"][0]
+    a0, a1 = annotations["clock_probe"]
+    s0 = ev["ts"] * 1e3 - start
+    assert abs(s0 - a0) < 1e6
+    assert abs(s0 + ev["dur"] * 1e3 - a1) < 1e6
+    # JAX's compile phases reach the trace under the program's name
+    assert "compile:jit(<lambda>)" in annotations
 
 
 # ---------------------------------------------------------------------------
@@ -428,7 +622,8 @@ def test_cli_demo_and_report(tmp_path):
     assert proc.returncode == 0, proc.stdout + proc.stderr
     obs.validate_chrome_trace(json.loads(open(trace).read()))
     reg = obs.MetricsRegistry.load(metrics)
-    assert {"telemetry", "spans"} <= set(reg.sections())
+    assert {"telemetry", "spans", "compiles"} <= set(reg.sections())
+    assert "jit(scan)" in reg.get("compiles")["programs"]
     proc = subprocess.run(
         [sys.executable, "-m", "repro.obs", "report", metrics],
         capture_output=True, text=True, env=env, timeout=120)
