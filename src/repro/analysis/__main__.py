@@ -55,14 +55,15 @@ def _default_paths() -> List[str]:
 
 def retrace_guard() -> List[Finding]:
     """The compile-once invariants, checked live on a tiny problem:
-    one ``weighted_gram`` entry per fit, one ``plan_step`` trace per
-    sweep, one GEMM compile per serve bucket (and zero for a repeat
-    bucket)."""
+    one ``weighted_gram`` entry per fit, one ``plan_step`` trace for two
+    fits of one shape (the scan is a cached program) and per sweep, one
+    GEMM compile per serve bucket (and zero for a repeat bucket)."""
     import jax.numpy as jnp
 
     from repro.analysis.jaxpr_audit import (_tiny_problem,
                                             jit_cache_size,
                                             trace_counter)
+    from repro.engine import plan as engine_plan
     from repro.engine.plan import compile_problem
     from repro.engine.sweep import compile_sweep
     from repro.serve import model as serve_model
@@ -76,13 +77,15 @@ def retrace_guard() -> List[Finding]:
                 f"{what}: expected exactly {want}, measured {got}"))
 
     prob = _tiny_problem()
+    engine_plan._plan_scan.clear_cache()       # count from a cold scan
     with trace_counter("repro.kernels.ops:weighted_gram",
                        "repro.engine.plan:plan_step") as c:
-        plan = compile_problem(prob, qp_iters=2)
-        plan.run(iters=3)
-    expect("fit", c["weighted_gram"], 1,
-           "weighted_gram entries per fit")
-    expect("fit", c["plan_step"], 1, "plan_step traces per fit")
+        for _ in range(2):
+            compile_problem(prob, qp_iters=2).run(iters=3)
+    expect("fit", c["weighted_gram"], 2,
+           "weighted_gram entries over two fits")
+    expect("fit", c["plan_step"], 1,
+           "plan_step traces over two fits of one shape")
 
     with trace_counter("repro.kernels.ops:weighted_gram",
                        "repro.engine.sweep:plan_step") as c:

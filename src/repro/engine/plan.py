@@ -24,6 +24,7 @@ recomputes each iteration.
 """
 from __future__ import annotations
 
+import functools
 import hashlib
 from typing import Callable, Optional
 
@@ -34,6 +35,7 @@ import numpy as np
 from repro.core import dtsvm as core
 from repro.engine import invariants as inv_lib
 from repro.engine import qp_engines
+from repro.kernels import ops as kops
 from repro.obs import spans as obs_spans
 
 DEFAULT_QP_SOLVER = "fista"
@@ -125,6 +127,42 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
     return core.DTSVMState(r=r_new, alpha=alpha, beta=beta, lam=lam)
 
 
+def _scan(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
+          state: core.DTSVMState, *, iters: int, qp_iters: int,
+          qp_solver: str, qp_precision: str, qp_operator: str,
+          telemetry=None, eval_fn: Optional[Callable] = None,
+          nbr_reduce: Optional[Callable] = None):
+    """``lax.scan`` of ``iters`` ``plan_step``s from ``state``: returns
+    ``(state, outs)``, ``outs`` stacking ``eval_fn(state)`` (0 without
+    one) after every iteration, paired with the telemetry streams when
+    ``telemetry`` is given.  The body of ``Plan.run``."""
+    def body(st, _):
+        with obs_spans.scope("scan_execute"):
+            new = plan_step(prob, inv, st, qp_iters=qp_iters,
+                            qp_solver=qp_solver, qp_precision=qp_precision,
+                            qp_operator=qp_operator, nbr_reduce=nbr_reduce)
+            out = eval_fn(new) if eval_fn is not None else jnp.float32(0)
+            if telemetry is not None:
+                out = (out, telemetry.collect(prob, inv.hi, new, st))
+        return new, out
+
+    return jax.lax.scan(body, state, None, length=iters)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "iters", "qp_iters", "qp_solver", "qp_precision", "qp_operator",
+    "telemetry", "_pallas"))
+def _plan_scan(prob, inv, state, *, iters, qp_iters, qp_solver,
+               qp_precision, qp_operator, telemetry, _pallas):
+    """``_scan`` as one cached program (``Plan.run``'s default path).
+    The Pallas engines read the kernel switch at trace time, so the
+    cache is keyed on it (``_pallas``), as the streamed Gram builds'
+    is."""
+    return _scan(prob, inv, state, iters=iters, qp_iters=qp_iters,
+                 qp_solver=qp_solver, qp_precision=qp_precision,
+                 qp_operator=qp_operator, telemetry=telemetry)
+
+
 class Plan:
     """A compiled DTSVM problem: invariants + the light per-iteration body.
 
@@ -182,32 +220,36 @@ class Plan:
         outputs are bitwise identical to the telemetry-None call, and
         the collector traces once inside the same scan body (zero extra
         retraces).  The streams are still on device; materialize them
-        after the scan (``repro.obs.materialize``)."""
+        after the scan (``repro.obs.materialize``).
+
+        Without ``eval_fn`` or a plan-level ``nbr_reduce`` the scan is
+        the module-level jit ``_plan_scan``, fed the problem, invariants
+        and state as arguments and keyed on their shapes, the QP
+        configuration, ``iters``, the telemetry spec and the Pallas
+        switch: the first run of a shape traces, lowers and compiles it,
+        every later run (any plan of that shape, e.g. the next fit on new
+        data or a replanned session stage) dispatches the compiled
+        program.  A hook callable would key the cache on its identity (a
+        per-call closure misses every time and keeps its arrays alive in
+        the cache), so with one the scan runs eagerly, traced anew each
+        call."""
         if state is None:
             state = self.init_state()
+        kw = dict(iters=int(iters), qp_iters=self.qp_iters,
+                  qp_solver=self.qp_solver, qp_precision=self.qp_precision,
+                  qp_operator=self.qp_operator, telemetry=telemetry)
+        span_args = {} if telemetry is None else {"telemetry": True}
+        with obs_spans.span("scan_execute", iters=int(iters), **span_args):
+            if eval_fn is None and self._nbr_reduce is None:
+                state, outs = _plan_scan(self.prob, self.inv, state, **kw,
+                                         _pallas=kops._use_pallas())
+            else:
+                state, outs = _scan(self.prob, self.inv, state,
+                                    eval_fn=eval_fn,
+                                    nbr_reduce=self._nbr_reduce, **kw)
         if telemetry is None:
-            def body(st, _):
-                with obs_spans.scope("scan_execute"):
-                    st = self.step(st)
-                    out = (eval_fn(st) if eval_fn is not None
-                           else jnp.float32(0))
-                return st, out
-
-            with obs_spans.span("scan_execute", iters=int(iters)):
-                state, hist = jax.lax.scan(body, state, None, length=iters)
-            return state, (hist if eval_fn is not None else None)
-
-        def body(st, _):
-            with obs_spans.scope("scan_execute"):
-                new = self.step(st)
-                out = eval_fn(new) if eval_fn is not None else jnp.float32(0)
-                tel = telemetry.collect(self.prob, self.inv.hi, new, st)
-            return new, (out, tel)
-
-        with obs_spans.span("scan_execute", iters=int(iters),
-                            telemetry=True):
-            state, (hist, streams) = jax.lax.scan(body, state, None,
-                                                  length=iters)
+            return state, (outs if eval_fn is not None else None)
+        hist, streams = outs
         return state, (hist if eval_fn is not None else None), streams
 
     # -- identity --------------------------------------------------------

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import functools
 import os
 
 import jax
@@ -82,19 +83,32 @@ def weighted_gram(Z: jnp.ndarray, a: jnp.ndarray, *,
         Z = jnp.broadcast_to(Z, a.shape[:-1] + Z.shape[-2:])
     if not _use_pallas():
         return ref.weighted_gram(Z, a)
+    tile = None if tile is None else tuple(tile)
+    if Z.ndim == 2:
+        return _gram_2d(Z, a, tile)
+    return _gram_map(Z, a, tile=tile)
+
+
+def _gram_2d(Z, a, tile):
+    """The Pallas Gram of one (N, D) block: the square kernel, or the
+    tiled one under an explicit ``tile``."""
     if tile is None:
-        fn = gram_kernel.weighted_gram_2d
-    else:
-        tile = tuple(tile)
-        fn = lambda z2, a1: gram_kernel.weighted_gram_tiled(
-            z2, a1, z2, tile=tile)
+        return gram_kernel.weighted_gram_2d(Z, a)
+    return gram_kernel.weighted_gram_tiled(Z, a, Z, tile=tile)
+
+
+@functools.partial(jax.jit, static_argnames=("tile",))
+def _gram_map(Z, a, *, tile):
+    """The Pallas Gram over Z's leading batch dims, one block at a time
+    (``lax.map``), as one cached program: lowered once per shape and
+    tile, not once per call.  Only the Pallas branch of
+    ``weighted_gram`` reaches it (the switch is read before the call),
+    so the switch needs no key."""
     batch = Z.shape[:-2]
-    if batch:
-        flatZ = Z.reshape((-1,) + Z.shape[-2:])
-        flata = a.reshape((-1,) + a.shape[-1:])
-        out = jax.lax.map(lambda za: fn(*za), (flatZ, flata))
-        return out.reshape(batch + out.shape[-2:])
-    return fn(Z, a)
+    flatZ = Z.reshape((-1,) + Z.shape[-2:])
+    flata = a.reshape((-1,) + a.shape[-1:])
+    out = jax.lax.map(lambda za: _gram_2d(*za, tile), (flatZ, flata))
+    return out.reshape(batch + out.shape[-2:])
 
 
 def weighted_gram_rows(Zm: jnp.ndarray, a: jnp.ndarray, Zn: jnp.ndarray, *,

@@ -7,8 +7,8 @@ name (``fun_name``) and, through the time-span listener, its start and
 end.  This module registers one listener pair, once, when ``repro.obs``
 is imported, and for every phase event:
 
-- counts it by program (``jit(scan)``, ``jit(_streamed_gram_jit)``, ...)
-  and by the innermost ``obs`` span open on the thread
+- counts it by program (``jit(_plan_scan)``, ``jit(_streamed_gram_jit)``,
+  ...) and by the innermost ``obs`` span open on the thread
   (``spans.current_span``) — "does my fit recompile every call?" is
   ``compile_counts()["programs"]`` read before and after a fit;
 - records it in the span recorder as ``compile:<program>`` (``args``:

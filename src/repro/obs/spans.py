@@ -44,8 +44,10 @@ name                    phase
                         dense Gram/Lipschitz pair
 ``plan_replan``         span: ``Plan.replan`` (incremental rebuild)
 ``scan_execute``        span: ``Plan.run``'s ADMM scan call, which times
-                        the scan's trace, lowering, compile and dispatch
-                        — not its execution, which is asynchronous;
+                        the scan's dispatch (and its trace, lowering and
+                        compile on the first run of a shape, or on every
+                        run with an ``eval_fn``) — not its execution,
+                        which is asynchronous;
                         scope: the scan body, whose device ops are the
                         execution
 ``qp_solve``            scope: the dual box-QP engine call of

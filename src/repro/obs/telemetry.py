@@ -63,7 +63,8 @@ class Telemetry:
     """An immutable telemetry spec: which streams to collect.
 
     Instances are plain host-side configuration — they carry no arrays,
-    so passing one into a traced region cannot change a trace cache key.
+    and two specs of the same streams are equal and hash alike, so a
+    spec can key a jit cache (``engine.plan``'s cached scan) by value.
     The default collects every stream in :data:`STREAMS`.
     """
 
@@ -80,6 +81,13 @@ class Telemetry:
         (delegates to :func:`collect_diagnostics`)."""
         return collect_diagnostics(prob, hi, new_state, prev_state,
                                    streams=self.streams)
+
+    def __eq__(self, other):
+        return (type(other) is type(self)
+                and other.streams == self.streams)
+
+    def __hash__(self):
+        return hash((type(self), self.streams))
 
     def __repr__(self):
         return f"Telemetry(streams={list(self.streams)})"

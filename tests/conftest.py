@@ -10,6 +10,26 @@ sys.path.insert(0, _HERE)
 sys.path.insert(0, os.path.join(os.path.dirname(_HERE), "src"))
 
 
+#: the fit's cached programs: module-level jits that live for the process
+_FIT_PROGRAMS = (("repro.engine.plan", "_plan_scan"),
+                 ("repro.kernels.ops", "_gram_map"))
+
+
+@pytest.fixture(autouse=True)
+def _cold_fit_programs():
+    """Start every test with the fit's cached programs cold.
+
+    A test that counts traces, or that monkeypatches a function these
+    programs call (the benchmark's broken-fit tests patch ``plan_step``
+    and ``consensus_update``), must trace them anew, not dispatch what
+    an earlier test of the same worker compiled.  A module not yet
+    imported has cached nothing."""
+    for module, program in _FIT_PROGRAMS:
+        if module in sys.modules:
+            getattr(sys.modules[module], program).clear_cache()
+    yield
+
+
 @pytest.fixture(autouse=True, scope="module")
 def _release_compiled_executables():
     """Drop jit caches after each test module.

@@ -27,12 +27,18 @@ def _data():
 
 
 def test_fit_builds_invariants_once_and_traces_step_once():
+    """One Gram build per fit; the scan is a cached program, so the
+    first fit of a shape traces the step once and the next fit of the
+    same shape (new data) not at all."""
     X, y, mask, adj = _data()
     with trace_counter("repro.kernels.ops:weighted_gram",
                        "repro.engine.plan:plan_step") as c:
         api.DTSVM(iters=3, qp_iters=2).fit(X, y, mask, adj)
-    assert c["weighted_gram"] == 1     # one invariant build per fit
-    assert c["plan_step"] == 1         # one trace for the whole scan
+        assert c["weighted_gram"] == 1     # one invariant build per fit
+        assert c["plan_step"] == 1         # one trace for the whole scan
+        api.DTSVM(iters=3, qp_iters=2).fit(X * 2.0, y, mask, adj)
+    assert c["weighted_gram"] == 2
+    assert c["plan_step"] == 1             # the second fit traces nothing
 
 
 def test_sweep_fit_is_one_trace_for_all_configs():

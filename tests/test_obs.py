@@ -138,14 +138,20 @@ def test_telemetry_bitwise_invisible_multidevice(backend):
 def test_telemetry_adds_zero_retraces():
     """With telemetry on, the fit still builds invariants once and
     traces the step once — and the collector itself traces exactly once,
-    inside the same scan body."""
+    inside the same scan body.  The cached scan is keyed on the spec's
+    value, so a second fit (a fresh ``Telemetry()``) traces nothing."""
     X, y, mask, adj = _data()
     with trace_counter("repro.kernels.ops:weighted_gram",
                        "repro.engine.plan:plan_step",
                        "repro.obs.telemetry:collect_diagnostics") as c:
         api.DTSVM(iters=4, qp_iters=2, telemetry=True).fit(
             X, y, mask, adj)
-    assert c["weighted_gram"] == 1
+        assert c["weighted_gram"] == 1
+        assert c["plan_step"] == 1
+        assert c["collect_diagnostics"] == 1
+        api.DTSVM(iters=4, qp_iters=2, telemetry=True).fit(
+            X, y, mask, adj)
+    assert c["weighted_gram"] == 2           # one build per fit
     assert c["plan_step"] == 1
     assert c["collect_diagnostics"] == 1
 
@@ -397,7 +403,7 @@ def _instructions(text):
 
 
 FITS = {
-    "vmap_scan": (dict(iters=2, qp_iters=4), "jit_scan", PHASES),
+    "vmap_scan": (dict(iters=2, qp_iters=4), "jit__plan_scan", PHASES),
     "budgeted_build": (dict(iters=2, qp_iters=4,
                             budget=api.PlanBudget(max_elems=64)),
                        "jit__streamed_gram_jit", ("invariant_build",)),
@@ -623,7 +629,7 @@ def test_cli_demo_and_report(tmp_path):
     obs.validate_chrome_trace(json.loads(open(trace).read()))
     reg = obs.MetricsRegistry.load(metrics)
     assert {"telemetry", "spans", "compiles"} <= set(reg.sections())
-    assert "jit(scan)" in reg.get("compiles")["programs"]
+    assert "jit(_plan_scan)" in reg.get("compiles")["programs"]
     proc = subprocess.run(
         [sys.executable, "-m", "repro.obs", "report", metrics],
         capture_output=True, text=True, env=env, timeout=120)
