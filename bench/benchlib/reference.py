@@ -24,7 +24,11 @@ Every contraction runs at ``precision``: ``"highest"`` (float32, what the
 configurations state) or ``"high"`` (three bfloat16 passes: each operand
 split into a bfloat16 head and tail, the tail-by-tail product dropped),
 the control that must come out not correct.  The split is written out,
-so the control computes the same on any backend.
+so the control computes the same on any backend.  The head is rounded
+on the operand's bits, not through a bfloat16 round trip: under
+XLA's excess precision a compiler may skip such a round trip inside a
+fusion (the TPU's does), which leaves the tail 0 and the control one
+pass.  The passes take the bfloat16 halves themselves as operands.
 """
 from __future__ import annotations
 
@@ -35,21 +39,28 @@ import numpy as np
 
 
 def _split(x):
+    """bfloat16 head and tail of a float32 array: the head is ``x``
+    rounded to bfloat16 (to nearest, ties to even) on its bits, the
+    tail the rest, ``x - head`` (exact in float32), rounded to
+    bfloat16."""
     import jax.numpy as jnp
+    from jax import lax
 
-    hi = x.astype(jnp.bfloat16).astype(jnp.float32)
-    lo = (x - hi).astype(jnp.bfloat16).astype(jnp.float32)
-    return hi, lo
+    bits = lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
+    bits = bits + jnp.uint32(0x7FFF) + ((bits >> 16) & jnp.uint32(1))
+    hi = lax.bitcast_convert_type(bits & jnp.uint32(0xFFFF0000), jnp.float32)
+    return hi.astype(jnp.bfloat16), (x - hi).astype(jnp.bfloat16)
 
 
 def contract(spec: str, a, b, precision: str):
     """``einsum(spec, a, b)`` in float32 (``"highest"``) or in three
-    bfloat16 passes (``"high"``)."""
+    bfloat16 passes (``"high"``), each accumulated in float32."""
     import jax.numpy as jnp
     from jax import lax
 
     ein = functools.partial(jnp.einsum, spec,
-                            precision=lax.Precision.HIGHEST)
+                            precision=lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     if precision == "highest":
         return ein(a, b)
     if precision != "high":

@@ -31,7 +31,7 @@ import sys
 import time
 from typing import Dict, Optional
 
-from benchlib import compare, counters, spec as spec_lib
+from benchlib import compare, counters, memory, spec as spec_lib
 from benchlib import trace as trace_lib
 
 
@@ -66,12 +66,14 @@ def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
-def memory_peak_bytes(devices) -> int:
-    peaks = []
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peaks.append(int(stats.get("peak_bytes_in_use", 0)))
-    return max(peaks) if peaks else 0
+def e2e_value(name: str, e2e: dict):
+    """The window's value of an end-to-end metric.  A metric named
+    ``<quantity>.<group>`` (``fit_s.large``) is the traffic kind's
+    ``<quantity>`` under a name, and a bound, of its own for a group of
+    cells."""
+    if name in e2e:
+        return e2e[name]
+    return e2e.get(name.split(".", 1)[0])
 
 
 def _finite(x: float) -> float:
@@ -91,33 +93,36 @@ def run_cell(spec: spec_lib.Spec, cell: str, *, seed: int, seconds: float,
     run = Run(spec, cell, seed, trace, overrides, mix_overrides, control)
     run.seconds = float(seconds)
     kind = run.kind
-    kind.setup(run)
-    setup_s = time.perf_counter() - t_start
-    log(f"bench: {cell} seed {seed} set-up {setup_s:.3f} s")
-
-    keep = trace_dir is not None
-    if trace:
-        trace_dir = trace_dir or os.path.join(spec.root, ".bench_trace",
-                                              cell)
-        shutil.rmtree(trace_dir, ignore_errors=True)
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    run.counter.armed = True
+    programs = memory.ProgramMemory().start()
     try:
-        with jax.profiler.TraceAnnotation(trace_lib.WINDOW_SPAN):
-            res = kind.window(run, seconds)
-    finally:
-        run.counter.armed = False
+        kind.setup(run)
+        setup_s = time.perf_counter() - t_start
+        log(f"bench: {cell} seed {seed} set-up {setup_s:.3f} s")
+        keep = trace_dir is not None
         if trace:
-            jax.profiler.stop_trace()
+            trace_dir = trace_dir or os.path.join(spec.root, ".bench_trace",
+                                                  cell)
+            shutil.rmtree(trace_dir, ignore_errors=True)
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(trace_dir, profiler_options=opts)
+        run.counter.armed = True
+        try:
+            with jax.profiler.TraceAnnotation(trace_lib.WINDOW_SPAN):
+                res = kind.window(run, seconds)
+        finally:
+            run.counter.armed = False
+            if trace:
+                jax.profiler.stop_trace()
+    finally:
+        programs.stop()
     res["counters"] = run.counter.snapshot()
     summary = None
     if trace:
         summary = trace_lib.read_xplane(trace_lib.find_xplane(trace_dir))
         if not keep:
             shutil.rmtree(trace_dir, ignore_errors=True)
-    mem = memory_peak_bytes(run.devices)
+    mem = memory.peak_bytes(run.devices, programs)
     kind.release(run)
     values = kind.check(run)
     checks = compare.judge(values, run.workload["limits"])
@@ -133,14 +138,15 @@ def run_cell(spec: spec_lib.Spec, cell: str, *, seed: int, seconds: float,
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
     else:
         for m in spec.end_to_end(cell):
-            v = setup_s if m["name"] == "setup_s" else res["e2e"].get(
-                m["name"])
+            v = setup_s if m["name"] == "setup_s" else e2e_value(
+                m["name"], res["e2e"])
             if v is not None:
                 metrics[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
     d0 = run.devices[0]
     device = {"platform": d0.platform, "kind": d0.device_kind,
-              "count": len(jax.devices()), "memory_peak_bytes": mem}
+              "count": len(jax.devices()),
+              "memory_peak_bytes": mem["memory_peak_bytes"]}
     out = {"correct": bool(correct), "attempted": int(res["attempted"]),
            "failed": int(res["failed"]), "metrics": metrics,
            "device": device}
@@ -149,7 +155,8 @@ def run_cell(spec: spec_lib.Spec, cell: str, *, seed: int, seconds: float,
         device["window_s"] = summary.window_s()
         out["breakdown"] = {"device_ops": summary.top_ops(10),
                             "idle_gaps": summary.idle_gaps(10)}
-    out["notes"] = dict(res.get("notes", {}), compile=res["counters"])
+    out["notes"] = dict(res.get("notes", {}), compile=res["counters"],
+                        memory=mem)
     out["checks"] = {k: {"value": _finite(c["value"]), "limit": c["limit"]}
                      for k, c in checks.items()}
     for line in compare.check_lines(checks):

@@ -115,11 +115,17 @@ class Spec:
     def reader(self, metric: str) -> Callable:
         """``read(r)`` of a per-layer metric: a number, or None where
         the run holds nothing to read."""
-        path = os.path.join(self.bench_dir, "metrics",
-                            _check_name(metric) + ".py")
-        mod = _load_module(path, "bench_metric_" + metric.replace(".", "_")
-                           .replace("-", "_"))
-        return mod.read
+        return reader_of(metric, self.bench_dir)
+
+
+def reader_of(metric: str, bench_dir: str = BENCH_DIR) -> Callable:
+    """``read(r)`` of the metric whose reader is ``metrics/<metric>.py``
+    (a reader that reads what another one does, for other cells, takes
+    it from here)."""
+    path = os.path.join(bench_dir, "metrics", _check_name(metric) + ".py")
+    mod = _load_module(path, "bench_metric_" + metric.replace(".", "_")
+                       .replace("-", "_"))
+    return mod.read
 
 
 def load_all(spec: Spec, cell: str) -> Dict[str, object]:

@@ -29,13 +29,24 @@ OPS_LINE = "XLA Ops"
 COLLECTIVE = re.compile(
     r"(all-gather|all-reduce|reduce-scatter|collective-permute|all-to-all"
     r"|allgather|allreduce)", re.IGNORECASE)
+#: control flow whose body's operations the trace lists inside it: the
+#: loop spans its body and does no work of its own, so it overlaps nothing
+CONTAINER = re.compile(r"^%?(while|conditional|call)(\.\d+)?$")
 WINDOW_SPAN = "bench.window"
 #: host spans kept: the benchmark's own, the program's ``obs.spans``
-#: phases, and JAX's own trace / lower / compile / dispatch phases
+#: phases and ``compile:<program>`` annotations, and JAX's own trace /
+#: lower / compile / dispatch phases
 HOST_SPANS = re.compile(
     r"^(bench\.|invariant_build$|plan_compile$|plan_replan$|scan_execute$"
-    r"|serve_batch$|store_|trace_to_jaxpr|lower_sharding_computation$"
-    r"|backend_compile|PjitFunction\()")
+    r"|serve_batch$|store_|compile:|trace_to_jaxpr"
+    r"|lower_sharding_computation$|backend_compile|PjitFunction\()")
+
+
+def instruction(op_name: str) -> str:
+    """The instruction an op runs.  A TPU trace names each op by its HLO
+    text, ``%name = shape opcode(operands)``, whose operands name other
+    instructions (a fusion fed by an all-gather names it)."""
+    return op_name.split(" = ", 1)[0]
 
 
 def union(intervals: Iterable[Interval]) -> List[Interval]:
@@ -157,19 +168,22 @@ class TraceSummary:
                              within: Optional[str] = None) -> float:
         """Device time of collective operations that no other operation
         on the chip overlaps, inside the window (or inside its host spans
-        called ``within``)."""
+        called ``within``).  A loop or call that holds the collective in
+        its body (``CONTAINER``) is not another operation."""
         ops = self.ops.get(chip, [])
         coll = union((o.start, o.end) for o in ops
-                     if COLLECTIVE.search(o.name))
+                     if COLLECTIVE.search(instruction(o.name)))
         rest = union((o.start, o.end) for o in ops
-                     if not COLLECTIVE.search(o.name))
+                     if not COLLECTIVE.search(instruction(o.name))
+                     and not CONTAINER.match(instruction(o.name)))
         out = 0
         for lo, hi in self._in_window(within):
             out += total(subtract(clip(coll, lo, hi), clip(rest, lo, hi)))
         return out / 1e9
 
     def has_collectives(self, chip: int = 0) -> bool:
-        return any(COLLECTIVE.search(o.name) for o in self.ops.get(chip, []))
+        return any(COLLECTIVE.search(instruction(o.name))
+                   for o in self.ops.get(chip, []))
 
     # -- breakdown ----------------------------------------------------------
     def top_ops(self, n: int = 10, chip: int = 0) -> List[list]:

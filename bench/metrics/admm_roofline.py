@@ -1,7 +1,8 @@
-"""The ADMM scan's share of its roofline: the least time the chip needs
-for the work any implementation must do (one float32 read of Z per inner
-QP iteration, ``benchlib.workcount``), over the device time measured
-inside the ``bench.admm`` spans per fit."""
+"""The ADMM scan's share of its roofline: the least time a chip needs
+for its share of the work any implementation must do (one float32 read
+of Z per inner QP iteration, ``benchlib.workcount``, over the cell's
+chips), over chip 0's device time inside the ``bench.admm`` spans per
+fit."""
 from benchlib import peaks, workcount
 
 
@@ -13,6 +14,8 @@ def read(r):
         "bench.admm")
     if busy <= 0:
         return None
-    least = peaks.least_time_s(workcount.admm_flops(r.config),
-                               workcount.admm_bytes(r.config), r.device_kind)
+    chips = r.run.chips
+    least = peaks.least_time_s(workcount.admm_flops(r.config, chips),
+                               workcount.admm_bytes(r.config, chips),
+                               r.device_kind)
     return 100.0 * least / busy

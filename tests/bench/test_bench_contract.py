@@ -14,7 +14,7 @@ BENCH = os.path.join(ROOT, "bench")
 if BENCH not in sys.path:
     sys.path.insert(0, BENCH)
 
-from benchlib import spec as spec_lib  # noqa: E402
+from benchlib import runner, spec as spec_lib  # noqa: E402
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -208,3 +208,14 @@ def test_missing_parts_are_errors(tmp_path):
         spec.reader("no.such.metric")
     with pytest.raises(spec_lib.SpecError):
         spec.config("../escape")
+
+
+@pytest.mark.parametrize("name,want", [
+    ("fit_s", 1.5),             # the kind's own quantity
+    ("fit_s.large", 1.5),       # the same quantity under a group's name
+    ("request_p95_ms", None),   # not in this window
+])
+def test_end_to_end_value_by_name(name, want):
+    """A metric ``<quantity>.<group>`` is the traffic kind's ``<quantity>``
+    under a name, and a bound, of its own."""
+    assert runner.e2e_value(name, {"fit_s": 1.5}) == want

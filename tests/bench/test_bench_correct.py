@@ -30,7 +30,8 @@ SOLVER = {"C": 0.01, "eps1": 1.0, "eps2": 1.0, "eta1": 1.0, "eta2": 1.0,
           "backend": "vmap"}
 SMALL = {
     "paper.fit": {},
-    "large_n.fit": dict(V=4, T=2, N=64, p=8, iters=4, qp_iters=12, solver=SOLVER,
+    "large_n.fit": dict(V=4, T=2, n_total=[256, 256], N=64, p=8, iters=4,
+                        qp_iters=12, solver=SOLVER,
                         budget={"max_elems": 2048}),
     "paper.serve": {},
 }
@@ -67,10 +68,11 @@ def test_traced_run_reads_its_per_layer_metrics(tmp_path):
                           overrides=SMALL["paper.fit"],
                           trace_dir=str(tmp_path / "trace"))
     assert out["correct"], out["checks"]
-    # every whole fit re-lowers its ADMM scan once (the program's own
-    # behaviour); split fits, which feed the build/ADMM spans, alternate
+    # the warm fits of set-up lower the fit's two programs (the ADMM scan
+    # and the Gram map, each cached per shape), so the window's whole fits
+    # lower none; split fits, which feed the build/ADMM spans, alternate
     # with them and are not counted
-    assert out["metrics"]["fit.lowerings_per_fit"]["value"] == 1.0
+    assert out["metrics"]["fit.lowerings_per_fit"]["value"] == 0.0
     assert out["notes"]["split_fits"] == 3 and out["notes"]["fits"] == 6
     # no TPU plane on the CPU: the device readers find nothing to read
     assert "fit.device_idle_pct" not in out["metrics"]
@@ -151,6 +153,14 @@ X4 = textwrap.dedent("""
             return jnp.concatenate([x] * n, axis=axis) if tiled else \\
                 jnp.stack([x] * n, axis=axis)
         jax.lax.all_gather = local
+    if {fault!r}:
+        # a fault of this file, planted as its monkeypatch would plant it
+        sys.path.insert(0, {here!r})
+        import test_bench_correct as faults
+
+        class _Patch:
+            setattr = staticmethod(setattr)
+        getattr(faults, {fault!r})(_Patch)
     spec = spec_lib.Spec({root!r}, {bench!r})
     out = runner.run_cell(spec, "large_n_x4.fit", seed={seed}, seconds=0.5,
                           trace=False, t_start=time.perf_counter(),
@@ -159,19 +169,43 @@ X4 = textwrap.dedent("""
 """)
 
 
-@pytest.mark.parametrize("broken", [False, True])
-def test_sample_shard_exchange(broken):
-    over = dict(V=4, T=2, N=64, p=8, iters=4, qp_iters=12, solver=dict(SOLVER,
-                                                 backend="sample_shard"),
+def _run_x4(broken=False, fault=""):
+    """``large_n_x4.fit`` cut to fit a CPU, on four host devices in a
+    child process; its stdout: the checks, then ``correct``."""
+    over = dict(V=2, T=2, n_total=[128, 128], N=64, p=8, iters=4,
+                qp_iters=12, solver=dict(SOLVER, backend="sample_shard"),
                 budget={"max_elems": 2048})
     code = X4.format(bench=BENCH, src=os.path.join(ROOT, "src"), root=ROOT,
-                     seed=SEED, over=over, broken=broken)
+                     here=HERE, seed=SEED, over=over, broken=broken,
+                     fault=fault)
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     p = subprocess.run([sys.executable, "-c", code], env=env,
                        capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
-    assert p.stdout.strip().endswith(str(not broken)), p.stdout
+    return p.stdout.strip()
+
+
+@pytest.mark.parametrize("broken", [False, True])
+def test_sample_shard_exchange(broken):
+    out = _run_x4(broken=broken)
+    assert out.endswith(str(not broken)), out
+
+
+def _consensus_kept(monkeypatch):
+    """Eqs. (7)-(9) return the state they were given: the
+    ``sample_shard`` step keeps its state (it has its own step, which
+    calls ``plan.consensus_update``, not ``plan.plan_step``)."""
+    from repro.engine import plan
+    monkeypatch.setattr(plan, "consensus_update", lambda prob, state, *a, **k:
+                        (state.r, state.alpha, state.beta))
+
+
+@pytest.mark.parametrize("fault", ["_consensus_kept", "_half_batch",
+                                   "_answer_altered"])
+def test_broken_sample_shard_fit_is_not_correct(fault):
+    out = _run_x4(fault=fault)
+    assert out.endswith("False"), out
 
 
 @pytest.mark.parametrize("cell", ["paper.fit", "large_n.fit", "paper.serve"])
@@ -196,7 +230,9 @@ def _load_cfg(name):
 
 @pytest.mark.parametrize("cell,cfg_name,over", [
     ("paper.fit", "paper", {}),                     # the cell's own size
-    ("large_n.fit", "large_n", {"N": 1024}),        # K cut to fit a CPU
+    ("large_n.fit", "large_n", {}),                 # the cell's own size
+    # the reference never holds K; N=24000 takes a minute on a CPU
+    ("large_n_x4.fit", "large_n_x4", {"N": 12000, "n_total": [24000, 24000]}),
 ])
 def test_control_fails_the_fit_limit(cell, cfg_name, over):
     cfg = dict(_load_cfg(cfg_name), **over)
@@ -206,6 +242,33 @@ def test_control_fails_the_fit_limit(cell, cfg_name, over):
     ref = reference.dtsvm_fit(cfg, *args, precision="highest")
     ctl = reference.dtsvm_fit(cfg, *args, precision="high")
     assert compare.state_rel_err(ctl, ref) > _limit(cell, "state_rel_err")
+
+
+def test_control_split_is_three_bfloat16_passes():
+    """The control's operands: a bfloat16 head (the operand rounded to
+    nearest) and a bfloat16 tail that is not 0; three passes land well
+    inside one bfloat16 pass and well outside float32."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jax.random.normal(jax.random.PRNGKey(3), (8, 512, 785))
+    y = jax.random.normal(jax.random.PRNGKey(4), (8, 785))
+    hi, lo = reference._split(x)
+    assert hi.dtype == lo.dtype == jnp.bfloat16
+    assert bool(jnp.all(hi == x.astype(jnp.bfloat16)))
+    assert float(jnp.mean(lo != 0)) > 0.9
+    exact = np.einsum("bnd,bd->bn", np.asarray(x, np.float64),
+                      np.asarray(y, np.float64))
+
+    def err(v):
+        return float(np.abs(np.asarray(v, np.float64) - exact).max()
+                     / np.abs(exact).max())
+    one = jnp.einsum("bnd,bd->bn", x.astype(jnp.bfloat16),
+                     y.astype(jnp.bfloat16),
+                     preferred_element_type=jnp.float32)
+    three = reference.contract("bnd,bd->bn", x, y, "high")
+    f32 = reference.contract("bnd,bd->bn", x, y, "highest")
+    assert err(f32) < 1e-6 < err(three) < 2e-5 < 10 * err(three) < err(one)
 
 
 def test_control_fails_the_serve_limit():

@@ -100,3 +100,24 @@ def test_compile_reader_reads_nothing_without_compile_spans(recorder):
     assert read(_Reading(_trace())) is None       # a program without them
     assert read(_Reading(None)) is None
     assert progspans.on_trace(_trace(), "compile:") is None
+
+
+def test_compile_reader_falls_back_to_the_trace_annotations(recorder):
+    """A backend that records no program span inside its fits (so no
+    offset is agreed) still has the ``compile:`` annotations the program
+    writes into the trace: those are read, in whole fits only."""
+    us = 1000
+    trace = _trace()
+    trace.spans = [s for s in trace.spans if s.name != "scan_execute"] + [
+        T.Span("compile:jit(run_shard)", 100 * us, 330 * us),
+        T.Span("compile:jit(run_shard)", 2100 * us, 2300 * us),
+        T.Span("compile:jit(other)", 1500 * us, 1600 * us)]   # between fits
+    _record(recorder, [("compile:jit(run_shard)", 1, 2)])
+    assert progspans.on_trace(trace, "compile:") is None
+    spec = spec_lib.Spec(ROOT, BENCH)
+    want = (230 + 200) / 2 / 1e3
+    assert spec.reader("fit.compile_ms_per_fit")(_Reading(trace)) == \
+        pytest.approx(want)
+    # the large cells' metric reads the same
+    assert spec.reader("fit.compile_ms_per_fit.large")(_Reading(trace)) == \
+        pytest.approx(want)

@@ -59,6 +59,21 @@ def test_collectives_only_where_nothing_else_runs():
     assert s.collective_exposed_s(0) == pytest.approx(5e-9)
 
 
+def test_collectives_inside_a_loop_are_not_hidden_by_it():
+    # as a TPU trace lists them: the loop spans its body's operations,
+    # and the fusion that consumes the gather names it as an operand
+    ops = {0: [T.Op("%while.7 = (s32[], f32[4,2,40000]) while(...)", 0, 100),
+               T.Op("%all-gather.3 = f32[4,2,40000] all-gather(...)", 20, 30),
+               T.Op("%fusion.1 = f32[4,2,10000] fusion(f32[4,2,40000] "
+                    "%all-gather.3)", 25, 40),
+               T.Op("%all-gather.3 = f32[4,2,40000] all-gather(...)", 60, 70)]}
+    s = T.TraceSummary(window=(0, 100), ops=ops,
+                       spans=[T.Span("bench.fit", 0, 100)])
+    # 20-25 and 60-70 run alone in the loop's body: 15 ns
+    assert s.collective_exposed_s(0, within="bench.fit") == \
+        pytest.approx(15e-9)
+
+
 def test_breakdown_names_ops_and_gaps():
     s = _summary()
     top = s.top_ops(2)
