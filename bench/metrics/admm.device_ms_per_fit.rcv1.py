@@ -1,0 +1,5 @@
+"""The sparse-text cell's reading of
+``admm.device_ms_per_fit``, where it moves ``fit_s.large``: the same reader."""
+from benchlib.spec import reader_of
+
+read = reader_of("admm.device_ms_per_fit")

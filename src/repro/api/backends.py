@@ -51,7 +51,7 @@ from __future__ import annotations
 from typing import Callable, Dict, Optional
 
 from repro.core import dtsvm as core
-from repro.core import dtsvm_dist
+from repro.core import dtsvm_dist, sparse
 from repro.engine import plan as engine_plan
 from repro.net import async_admm
 from repro.obs import spans as obs_spans
@@ -455,8 +455,11 @@ def run(prob: core.DTSVMProblem, iters: int, *, backend: str = "vmap",
     (``qp_precision="bf16"`` / ``qp_operator="factored"``) are a
     single-host plan feature: only the ``"vmap"`` backend threads them
     (any other backend raises on a non-default value — the sharded
-    paths carry their own dual layouts).
+    paths carry their own dual layouts).  So is a sparse X
+    (``core.sparse.SparseRows``): any other backend raises on it.
     """
+    if backend != "vmap":
+        sparse.require_dense(prob.X, f"the {backend!r} backend")
     if (qp_precision, qp_operator) != ("f32", "materialized"):
         if backend != "vmap":
             raise ValueError(

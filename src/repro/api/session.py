@@ -73,6 +73,7 @@ from repro.api import backends, evaluate
 from repro.api.solvers import SolverConfig, _as_solver_config, \
     effective_backend
 from repro.core import dtsvm as core
+from repro.core import sparse
 from repro.engine import plan as engine_plan
 from repro.obs import telemetry as obs_telemetry
 
@@ -98,6 +99,7 @@ class OnlineSession:
                  active=None, couple=None, X_test=None, y_test=None,
                  jit: bool = False, log=None, **overrides):
         self.config = _as_solver_config(config, overrides)
+        sparse.require_dense(X, "OnlineSession (its replans)")
         self._X = jnp.asarray(X, jnp.float32)
         self._y = jnp.asarray(y, jnp.float32)
         V, T, N, p = self._X.shape

@@ -37,6 +37,7 @@ from repro.api import backends, evaluate
 from repro.api.solvers import SolverConfig
 from repro.core import dsvm as dsvm_lib
 from repro.core import dtsvm as core
+from repro.core import sparse
 from repro.engine import sweep as sweep_lib
 
 
@@ -130,6 +131,7 @@ def sweep_fit(X, y, cfgs: Sequence, mask=None, adj=None, *,
     through bounded row panels — the sweep's K is S times a single
     fit's, so large grids hit memory first (API.md §scale).
     """
+    sparse.require_dense(X, "sweep_fit")
     base, cfgs = _split_grid(cfgs, base)
     prob = core.make_problem(
         X, y, mask, adj, C=base.C, eps1=base.eps1, eps2=base.eps2,

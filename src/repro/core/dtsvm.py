@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import qp as qp_lib
+from repro.core import sparse
 from repro.kernels import ops as kops
 from repro.obs import spans as obs_spans
 
@@ -65,7 +66,8 @@ class DTSVMState(NamedTuple):
 
 
 class DTSVMProblem(NamedTuple):
-    X: jnp.ndarray        # (V, T, N, p)
+    X: jnp.ndarray        # (V, T, N, p), or its nonzeros
+    #                       (core.sparse.SparseRows, vmap fits only)
     y: jnp.ndarray        # (V, T, N)  in {-1, +1}
     mask: jnp.ndarray     # (V, T, N)  in {0, 1}
     adj: jnp.ndarray      # (V, V) bool
@@ -84,7 +86,8 @@ class DTSVMProblem(NamedTuple):
 def make_problem(X, y, mask=None, adj=None, *, C=0.01, eps1=1.0, eps2=1.0,
                  eta1=1.0, eta2=1.0, box_scale=None, active=None,
                  couple=None) -> DTSVMProblem:
-    X = jnp.asarray(X, jnp.float32)
+    if not isinstance(X, sparse.SparseRows):
+        X = jnp.asarray(X, jnp.float32)
     y = jnp.asarray(y, jnp.float32)
     V, T, N, p = X.shape
     if mask is None:

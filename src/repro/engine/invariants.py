@@ -42,7 +42,9 @@ import numpy as np
 
 from repro.core import dtsvm as core
 from repro.core import qp as qp_lib
+from repro.core import sparse
 from repro.kernels import ops as kops
+from repro.obs import counters as obs_counters
 from repro.obs import spans as obs_spans
 
 
@@ -94,7 +96,8 @@ class PlanInvariants(NamedTuple):
     nbr: jnp.ndarray      # (V, T)
     u: jnp.ndarray        # (V, T, 2p+2)
     a: jnp.ndarray        # (V, T, p+1)
-    Z: jnp.ndarray        # (V, T, N, p+1)
+    Z: jnp.ndarray        # (V, T, N, p+1); a core.sparse.SparseZ
+    #                       when X is sparse
     K: Optional[jnp.ndarray]   # (V, T, N, N); None under the factored
     #                            operator (K is rank <= p+1 and the QP
     #                            matvec evaluates as Z (a (Z^T lam)) —
@@ -279,8 +282,11 @@ def compute_z(prob: core.DTSVMProblem) -> jnp.ndarray:
     data (X, y, mask), never on hyper-parameters or membership masks, so
     a hyper-parameter sweep (``engine.sweep``) builds it once and shares
     it across every config; only ``_masks_part`` + the Gram re-weighting
-    vary per config.
+    vary per config.  A sparse X (``core.sparse.SparseRows``) gives a
+    sparse Z (``core.sparse.SparseZ``).
     """
+    if isinstance(prob.X, sparse.SparseRows):
+        return sparse.compute_z(prob.X, prob.y, prob.mask)
     V, T, N, p = prob.X.shape
     Xa = jnp.concatenate([prob.X, jnp.ones((V, T, N, 1), jnp.float32)], -1)
     return prob.y[..., None] * Xa * prob.mask[..., None]
@@ -302,13 +308,27 @@ def compute_invariants(prob: core.DTSVMProblem, *,
     ``None`` and only the Gershgorin bound is computed, through
     discarded row panels (``streamed_lipschitz``) — the whole invariant
     set is O(N D) instead of O(N^2).
+
+    A sparse X builds K from its nonzeros, feature panel by feature
+    panel (``core.sparse.gram_and_lipschitz``, inside a ``sparse_gram``
+    span; ``budget`` does not apply), and adds the bytes of Z it writes
+    dense and the nonzeros it stores to the ``build.densified_bytes``
+    and ``build.stored_nonzeros`` counters (``repro.obs.counters``).
     """
     with obs_spans.span("invariant_build", budgeted=budget is not None,
                         materialize_k=materialize_k):
         ntp, nbr, u, a, hi = _masks_part(prob, nbr_counts)
         if Z is None:
             Z = compute_z(prob)
-        if materialize_k:
+        if isinstance(Z, sparse.SparseZ):
+            if not materialize_k:
+                sparse.require_dense(prob.X, "the factored QP operator")
+            obs_counters.count("build.densified_bytes",
+                               sparse.densified_bytes(prob.X.shape))
+            obs_counters.count("build.stored_nonzeros", prob.X.stored)
+            with obs_spans.span("sparse_gram"):
+                K, L = sparse.gram_and_lipschitz(Z, a)
+        elif materialize_k:
             K, L = gram_and_lipschitz(Z, a, budget)
         else:
             K, L = None, streamed_lipschitz(Z, a, budget)
@@ -330,6 +350,7 @@ def update_invariants(prob: core.DTSVMProblem, inv: PlanInvariants, *,
     row panels, so an online membership event at large n never
     materializes more Gram workspace than the original budgeted build.
     """
+    sparse.require_dense(prob.X, "an incremental re-plan")
     new_prob = prob
     if active is not None:
         new_prob = new_prob._replace(

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import dtsvm as core
+from repro.core import sparse
 from repro.engine import invariants as inv_lib
 from repro.engine import qp_engines
 from repro.kernels import ops as kops
@@ -100,9 +101,16 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
 
     f = core._f_vec(prob, state, ntp, nbr, nbr_reduce)
     g = f[..., : p + 1] / u[..., : p + 1] + f[..., p + 1:] / u[..., p + 1:]
-    # mul+reduce (not einsum): bitwise-stable under an extra vmapped
-    # config axis — the sweep engine relies on batched == serial exactly
-    q = prob.mask + jnp.sum(Z * g[..., None, :], axis=-1)
+    # a sparse Z (compile_problem admits it with the materialized QP
+    # only) takes both of its products over the nonzeros
+    is_sparse = isinstance(Z, sparse.SparseZ)
+    if is_sparse:
+        with obs_spans.scope("sparse_matvec"):
+            q = prob.mask + sparse.z_dot(Z, g)
+    else:
+        # mul+reduce (not einsum): bitwise-stable under an extra vmapped
+        # config axis — the sweep engine relies on batched == serial exactly
+        q = prob.mask + jnp.sum(Z * g[..., None, :], axis=-1)
 
     engine = qp_engines.get(qp_solver)
     # the dual solve and its X^T Y lam summary (one launch in the fused
@@ -116,6 +124,11 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
             lam, zl = engine(inv.K, q, inv.hi, state.lam, iters=qp_iters,
                              L=inv.L, precision=qp_precision,
                              Z=Z)                              # eq. (6)
+        elif is_sparse:
+            lam = engine(inv.K, q, inv.hi, state.lam,
+                         iters=qp_iters, L=inv.L)              # eq. (6)
+            with obs_spans.scope("sparse_matvec"):
+                zl = sparse.zt_dot(Z, lam)                     # X^T Y lam
         else:
             lam = engine(inv.K, q, inv.hi, state.lam,
                          iters=qp_iters, L=inv.L)              # eq. (6)
@@ -377,6 +390,12 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
             raise ValueError("qp_operator='factored' is f32-only "
                              "(the low-rank matvec never streams K "
                              "tiles, so bf16 K has nothing to apply to)")
+    if isinstance(prob.X, sparse.SparseRows) and (
+            qp_operator != "materialized" or qp_precision != "f32"
+            or getattr(engine, "supports_fold", False)):
+        sparse.require_dense(prob.X, f"qp_solver={qp_solver!r} with "
+                             f"qp_precision={qp_precision!r} and "
+                             f"qp_operator={qp_operator!r}")
     inv = inv_lib.compute_invariants(
         prob, nbr_counts=nbr_counts, budget=budget,
         materialize_k=(qp_operator != "factored"))

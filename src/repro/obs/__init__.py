@@ -16,6 +16,9 @@ so every layer of the system can import obs without cycles):
 - ``obs.compiles`` — JAX's trace / lower / compile events counted per
   program and per enclosing span, and recorded as ``compile:<program>``
   spans (registered when ``repro.obs`` is imported).
+- ``obs.counters`` — named host-side totals of work done
+  (``build.densified_bytes``, ``build.stored_nonzeros``), added from
+  static shapes: ``read_counters()`` before and after a fit.
 - ``obs.registry`` — ``MetricsRegistry``: one versioned JSON document
   absorbing ``net_report_``, serve stats, ``plan_stats`` and telemetry
   summaries; ``python -m repro.obs report`` renders it.
@@ -25,6 +28,7 @@ so every layer of the system can import obs without cycles):
 """
 from repro.obs.compiles import clear_compiles, compile_counts
 from repro.obs.compiles import register as _register_compiles
+from repro.obs.counters import clear_counters, count, read_counters
 from repro.obs.registry import OBS_SCHEMA_VERSION, MetricsRegistry
 from repro.obs.spans import (clear_spans, dropped_spans, iter_spans,
                              save_trace, scope, span, to_chrome_trace,
@@ -38,6 +42,7 @@ _register_compiles()
 __all__ = [
     "OBS_SCHEMA_VERSION", "MetricsRegistry",
     "clear_compiles", "compile_counts",
+    "clear_counters", "count", "read_counters",
     "clear_spans", "dropped_spans", "iter_spans", "save_trace", "scope",
     "span", "to_chrome_trace", "validate_chrome_trace",
     "STREAMS", "Telemetry", "collect_diagnostics", "concat_streams",

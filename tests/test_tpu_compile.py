@@ -98,3 +98,17 @@ def test_qp_pg_multi_compiles(one_chip, mode):
     calls, total = _compile(fn, one_chip, *shapes)
     assert calls >= 1
     assert 4 * N * N <= total < HBM_BYTES
+
+
+def test_sparse_panel_gram_compiles(one_chip):
+    """The sparse K build's step: one block's (N, panel) tile of densified
+    features through the square Gram kernel, at the rcv1_topics widths
+    (5788 documents a node-task, 1024-term panels).  A 2048-term panel
+    overruns the kernel's 16 MB of scoped VMEM."""
+    from repro.core import sparse
+
+    fn = functools.partial(gram_kernel.weighted_gram_2d, interpret=False)
+    panel = sparse.panel_width(47236)
+    calls, total = _compile(fn, one_chip, (5788, panel), (panel,))
+    assert calls >= 1
+    assert 4 * 5788 * 5788 <= total < HBM_BYTES
