@@ -125,7 +125,8 @@ def _bench_qp_modes(*, V=10, T=2, n_per_vt=128, p=10, iters=40,
                                          n_test=n_test, seed=seed)
     A = graph.make_graph("random", V, degree=0.8, seed=seed)
     base = SolverConfig(C=0.01, iters=iters, qp_iters=qp_iters,
-                        qp_solver="pallas_fused_multi")
+                        qp_solver="pallas_fused_multi",
+                        qp_operator="materialized")
     modes = {
         "fused_iterated": base.replace(qp_solver="pallas_fused"),
         "f32_materialized": base,
@@ -179,10 +180,11 @@ def _bench_convergence(*, V=10, T=2, n_per_vt=128, p=10, iters=40,
                                          n_test=64, seed=seed)
     A = graph.make_graph("random", V, degree=0.8, seed=seed)
     engines = {
-        "fista": {},
+        "fista": {"qp_operator": "materialized"},
         "pg": {"qp_solver": "pg"},
         "pallas_fused": {"qp_solver": "pallas_fused"},
-        "pallas_fused_multi": {"qp_solver": "pallas_fused_multi"},
+        "pallas_fused_multi": {"qp_solver": "pallas_fused_multi",
+                               "qp_operator": "materialized"},
         "factored": {"qp_solver": "pallas_fused_multi",
                      "qp_operator": "factored"},
     }

@@ -197,12 +197,14 @@ def _bench_fits(*, V, T, N, p, iters, qp_iters, max_elems,
         dense_state = None
         if assert_dense_equal:
             st, _ = backends.run(prob, iters, backend="vmap",
-                                 qp_iters=qp_iters, qp_solver=qp_solver)
+                                 qp_iters=qp_iters, qp_solver=qp_solver,
+                                 qp_operator="materialized")
             dense_state = jax.block_until_ready(st)
         for backend in ("vmap", "async"):
             t0 = time.time()
             st, _ = backends.run(prob, iters, backend=backend,
                                  qp_iters=qp_iters, qp_solver=qp_solver,
+                                 qp_operator="materialized",
                                  budget=budget)
             jax.block_until_ready(st.r)
             dt = time.time() - t0

@@ -39,10 +39,11 @@ from repro.obs import timing as obs_timing
 #: the same ADMM recursion, cheapest-to-build first.  fista is the
 #: reference rung every other rung's guardrail compares against.
 VARIANTS = [
-    ("fista", {}),
+    ("fista", {"qp_operator": "materialized"}),
     ("pg", {"qp_solver": "pg"}),
     ("pallas_fused", {"qp_solver": "pallas_fused"}),
-    ("pallas_fused_multi", {"qp_solver": "pallas_fused_multi"}),
+    ("pallas_fused_multi", {"qp_solver": "pallas_fused_multi",
+                            "qp_operator": "materialized"}),
     ("factored", {"qp_solver": "pallas_fused_multi",
                   "qp_operator": "factored"}),
 ]

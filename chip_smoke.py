@@ -298,7 +298,7 @@ def phase_large_n(seed):
     data, A = make_data(s["V"], s["T"], s["N"], s["p"], seed)
     budget = PlanBudget(max_elems=s["max_elems"])
     cfg = SolverConfig(C=0.01, iters=s["iters"], qp_iters=s["qp_iters"],
-                       budget=budget)
+                       budget=budget, qp_operator="materialized")
     multi = cfg.replace(qp_solver="pallas_fused_multi")
     factored = multi.replace(qp_operator="factored")
     log(f"   K = {4 * s['V'] * s['T'] * s['N'] ** 2 / 1e9:.1f} GB, "
@@ -308,12 +308,16 @@ def phase_large_n(seed):
         ref_multi = fit(DTSVM, multi, data, A)
     got = {"fista": fit(DTSVM, cfg, data, A),
            "pallas_fused_multi": fit(DTSVM, multi, data, A),
-           "factored": fit(DTSVM, factored, data, A)}
+           "factored": fit(DTSVM, factored, data, A),
+           "fista_factored": fit(DTSVM, cfg.replace(qp_operator="factored"),
+                                 data, A)}
     compare_fits("fista kernel vs oracle", got["fista"], ref_fista, data)
     compare_fits("pallas_fused_multi kernel vs oracle",
                  got["pallas_fused_multi"], ref_multi, data)
     compare_fits("factored vs multi oracle", got["factored"], ref_multi,
                  data)
+    compare_fits("factored fista vs fista oracle", got["fista_factored"],
+                 ref_fista, data)
     for name in ("pallas_fused_multi", "factored"):
         compare_fits(f"{name} vs fista oracle (risks)", got[name],
                      ref_fista, data, states=False)

@@ -70,9 +70,9 @@ HOT_ROOTS = frozenset({
     "plan_step", "consensus_update", "dtsvm_step", "_fabric_step",
     "gemm_rows", "reduce", "exchange", "_per_edge_quant",
     "apply_membership",
-    "solve_fista", "solve_pg", "solve_pallas_fused",
+    "solve_fista", "solve_fista_factored", "solve_pg", "solve_pallas_fused",
     "solve_pallas_fused_multi", "solve_factored_multi",
-    "solve_box_qp_pg", "solve_box_qp_fista",
+    "solve_box_qp_pg", "solve_box_qp_fista", "solve_box_qp_fista_factored",
     "weighted_gram", "weighted_gram_rows", "qp_pg_step", "qp_pg_multi",
     "_qp_rows",
     "collect_diagnostics", "collect_shard_diagnostics",

@@ -86,7 +86,7 @@ def names():
 @register("vmap")
 def _run_vmap(prob: core.DTSVMProblem, iters: int, *, qp_iters: int = 200,
               qp_solver: str = "fista", qp_precision: str = "f32",
-              qp_operator: str = "materialized",
+              qp_operator: Optional[str] = None,
               state: Optional[core.DTSVMState] = None, eval_fn=None,
               plan: Optional[engine_plan.Plan] = None, budget=None,
               telemetry=None, telemetry_out: Optional[dict] = None,
@@ -99,8 +99,10 @@ def _run_vmap(prob: core.DTSVMProblem, iters: int, *, qp_iters: int = 200,
         The problem to fit.
     iters : int
         ADMM iterations.
-    qp_iters, qp_solver
-        Inner dual solve configuration (``engine.qp_engines``).
+    qp_iters, qp_solver, qp_precision, qp_operator
+        Inner dual solve configuration (``engine.qp_engines``); a
+        ``qp_operator`` of None is chosen from the shapes
+        (``engine.plan.resolve_qp_operator``).
     state : core.DTSVMState, optional
         Warm start (zeros when omitted).
     eval_fn : callable, optional
@@ -134,7 +136,7 @@ def _run_vmap(prob: core.DTSVMProblem, iters: int, *, qp_iters: int = 200,
     elif (plan.prob is not prob or plan.qp_iters != qp_iters
           or plan.qp_solver != qp_solver
           or plan.qp_precision != qp_precision
-          or plan.qp_operator != qp_operator):
+          or qp_operator not in (None, plan.qp_operator)):
         raise ValueError(
             "prebuilt plan= disagrees with the call: pass prob=plan.prob "
             "and matching qp_iters/qp_solver/qp_precision/qp_operator "
@@ -440,7 +442,7 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
 
 def run(prob: core.DTSVMProblem, iters: int, *, backend: str = "vmap",
         qp_iters: int = 200, qp_solver: str = "fista",
-        qp_precision: str = "f32", qp_operator: str = "materialized",
+        qp_precision: str = "f32", qp_operator: Optional[str] = None,
         state=None, eval_fn=None, **options):
     """Dispatch one fit through the named backend.
 
@@ -457,15 +459,19 @@ def run(prob: core.DTSVMProblem, iters: int, *, backend: str = "vmap",
     (any other backend raises on a non-default value — the sharded
     paths carry their own dual layouts).  So is a sparse X
     (``core.sparse.SparseRows``): any other backend raises on it.
+    ``qp_operator=None`` lets the vmap plan choose the operator from the
+    shapes (``engine.plan.resolve_qp_operator``); the other backends run
+    it as materialized.
     """
     if backend != "vmap":
         sparse.require_dense(prob.X, f"the {backend!r} backend")
-    if (qp_precision, qp_operator) != ("f32", "materialized"):
-        if backend != "vmap":
+        if qp_precision != "f32" or qp_operator not in (None,
+                                                        "materialized"):
             raise ValueError(
                 f"qp_precision/qp_operator are vmap-backend features; "
                 f"backend={backend!r} runs the exact materialized-f32 "
                 f"dual path only")
+    else:
         options = dict(options, qp_precision=qp_precision,
                        qp_operator=qp_operator)
     return get(backend)(prob, iters, qp_iters=qp_iters, qp_solver=qp_solver,

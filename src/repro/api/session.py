@@ -323,7 +323,8 @@ class OnlineSession:
         (the untouched Gram slices are reused bit-for-bit)."""
         if self._plan is None:
             self._plan = engine_plan.compile_problem(
-                self.problem(), self.config)
+                self.problem(), self.config,
+                backend=self._effective_backend())
         elif self._masks_dirty:
             self._plan = self._plan.replan(active=self._active.copy(),
                                            couple=self._couple.copy())
@@ -372,8 +373,8 @@ class OnlineSession:
         iters = iters if iters is not None else cfg.iters
         self._emit("run", iters=int(iters), record=bool(record))
         with_eval = record and self._test is not None
-        default_qp_mode = (cfg.qp_precision, cfg.qp_operator) == (
-            "f32", "materialized")
+        default_qp_mode = (cfg.qp_precision == "f32"
+                           and cfg.qp_operator in (None, "materialized"))
         # the legacy jitted fast path runs the core loop, which only
         # knows the materialized f32 operator — non-default QP modes
         # and telemetry collection take the plan path below, which

@@ -66,11 +66,17 @@ class SolverConfig:
         engine.  Requires ``qp_solver="pallas_fused_multi"``;
         validated by the BENCH_fit risk-delta table, never claimed
         bitwise.
-    qp_operator : str
-        ``"materialized"`` (default) or ``"factored"`` — evaluate the
-        QP matvec as ``Z (a (Z^T lam))`` in O(N D) without ever
-        building K (the large-n fast path; K is rank <= p+1).
-        Requires ``qp_solver="pallas_fused_multi"`` and f32.
+    qp_operator : str, optional
+        ``"materialized"`` or ``"factored"`` — evaluate the QP matvec
+        as ``Z (a (Z^T lam))`` in O(N D) without ever building K (the
+        large-n fast path; K is rank <= p+1).  ``None`` (default)
+        chooses from the shapes (``engine.plan.resolve_qp_operator``):
+        factored on the ``vmap`` backend with ``qp_solver="fista"`` or
+        ``"pallas_fused_multi"``, f32 and a dense X where N > 2 (p+1)
+        and K would hold at least ``engine.plan.FACTORED_MIN_K_BYTES``;
+        materialized everywhere else.  An explicit value is honoured;
+        factored needs one of those engines, f32, a dense X and the
+        ``vmap`` backend.
     box_scale : float, optional
         The paper's multiplier on ``C`` in the QP box (auto: ``V*T``).
     backend : str
@@ -104,7 +110,8 @@ class SolverConfig:
     qp_solver: str = "fista"         # "fista" | "pg" | "pallas_fused"
     #                                  | "pallas_fused_multi"
     qp_precision: str = "f32"        # "f32" | "bf16" (multi engine only)
-    qp_operator: str = "materialized"   # "materialized" | "factored"
+    qp_operator: Optional[str] = None   # "materialized" | "factored";
+    #                                     None: chosen from the shapes
     box_scale: Optional[float] = None   # paper's V*T multiplier (auto)
     backend: str = "vmap"            # "vmap" | "shard_map" | "async"
     backend_options: Dict[str, Any] = field(default_factory=dict)

@@ -8,6 +8,8 @@ Solvers (all fixed-iteration ``jax.lax`` loops, jit/vmap-friendly):
 
 - ``solve_box_qp_pg``       projected-gradient ascent, Gershgorin step size
 - ``solve_box_qp_fista``    Nesterov-accelerated projected gradient
+- ``solve_box_qp_fista_factored``  the same on K = Z diag(a) Z^T, applied
+                            as ``Z (a (Z^T y))`` without building K
 - ``kkt_residual``          optimality measure used by tests
 
 K is PSD by construction (a Gram matrix), so the Gershgorin row-sum bound
@@ -55,20 +57,15 @@ def solve_box_qp_pg(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
     return jax.lax.fori_loop(0, iters, body, lam)
 
 
-def solve_box_qp_fista(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
-                       iters: int = 200, lam0=None,
-                       L: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    """FISTA-style accelerated projected gradient (monotone restart-free).
-    ``L``: optional precomputed Gershgorin bound."""
-    if L is None:
-        L = gershgorin_lipschitz(K)
+def _fista(matvec, q, hi, iters, lam0, L):
+    """The FISTA recursion on one box QP, K applied by ``matvec``."""
     step = 1.0 / L
     lam = jnp.zeros_like(q) if lam0 is None else _project(lam0, hi)
     state = (lam, lam, jnp.float32(1.0))
 
     def body(_, state):
         lam, y, t = state
-        grad = q - jnp.matmul(K, y, precision="highest")
+        grad = q - matvec(y)
         lam_new = _project(y + step * grad, hi)
         t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
         y_new = lam_new + ((t - 1.0) / t_new) * (lam_new - lam)
@@ -76,6 +73,32 @@ def solve_box_qp_fista(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
 
     lam, _, _ = jax.lax.fori_loop(0, iters, body, state)
     return lam
+
+
+def solve_box_qp_fista(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
+                       iters: int = 200, lam0=None,
+                       L: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """FISTA-style accelerated projected gradient (monotone restart-free).
+    ``L``: optional precomputed Gershgorin bound."""
+    if L is None:
+        L = gershgorin_lipschitz(K)
+    return _fista(lambda y: jnp.matmul(K, y, precision="highest"),
+                  q, hi, iters, lam0, L)
+
+
+def solve_box_qp_fista_factored(Z: jnp.ndarray, a: jnp.ndarray,
+                                q: jnp.ndarray, hi: jnp.ndarray,
+                                iters: int = 200, lam0=None, *,
+                                L: jnp.ndarray) -> jnp.ndarray:
+    """``solve_box_qp_fista`` on K = Z diag(a) Z^T (Z: (N, D), a: (D,))
+    applied through its factors, ``K y = Z (a * (Z^T y))`` in O(N D)
+    instead of O(N^2).  ``L`` is mandatory: there is no K to derive the
+    Gershgorin bound from."""
+    def matvec(y):
+        zt = jnp.matmul(y, Z, precision="highest")
+        return jnp.matmul(Z, a * zt, precision="highest")
+
+    return _fista(matvec, q, hi, iters, lam0, L)
 
 
 def qp_objective(K, q, lam):

@@ -37,9 +37,46 @@ from repro.core import sparse
 from repro.engine import invariants as inv_lib
 from repro.engine import qp_engines
 from repro.kernels import ops as kops
+from repro.obs import counters as obs_counters
 from repro.obs import spans as obs_spans
 
 DEFAULT_QP_SOLVER = "fista"
+
+#: The smallest dual Hessian (K's V*T*N*N float32 bytes) at which
+#: ``resolve_qp_operator`` picks the factored operator: below it a QP
+#: step is bound by its latency, not by HBM, and the materialized
+#: matvec's one read of K does as well as or better than the factored
+#: form's two reads of Z.  Read from whole fits of both operators on
+#: one TPU v5e chip (``benchmarks/qp_operator_crossover.py``; PERF.md
+#: section 6): the smallest measured K at which the factored fit won at
+#: 2 x 40 and at 100 x 100 iterations, and at every larger shape.
+FACTORED_MIN_K_BYTES = 32 << 20
+
+
+def resolve_qp_operator(qp_operator: Optional[str], *, qp_solver: str,
+                        qp_precision: str, x_shape, sparse_x: bool = False,
+                        backend: str = "vmap") -> str:
+    """The QP operator a fit runs: ``qp_operator`` when given, else
+    chosen from the problem's shape.
+
+    ``None`` resolves to ``"factored"`` only where each QP step then
+    reads fewer bytes and bandwidth sets its pace: the ``vmap`` backend
+    (the other backends carry their own dual layouts), an engine with a
+    factored form (``qp_engines``: ``"fista"``, ``"pallas_fused_multi"``),
+    f32, a dense X (``x_shape`` is X's (V, T, N, p)), two reads of Z
+    smaller than one of K (N > 2 (p+1)), and K of at least
+    :data:`FACTORED_MIN_K_BYTES`.  Everywhere else it resolves to
+    ``"materialized"``.
+    """
+    if qp_operator is not None:
+        return qp_operator
+    V, T, N, p = x_shape
+    factored = (backend == "vmap" and not sparse_x and qp_precision == "f32"
+                and getattr(qp_engines.get(qp_solver), "factored", None)
+                is not None
+                and N > 2 * (p + 1)
+                and 4 * V * T * N * N >= FACTORED_MIN_K_BYTES)
+    return "factored" if factored else "materialized"
 
 
 def consensus_update(prob: core.DTSVMProblem, state: core.DTSVMState,
@@ -88,11 +125,13 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
 
     ``qp_precision`` / ``qp_operator`` select the mixed-precision and
     factored-matvec QP modes (validated by ``compile_problem``; both
-    default to the exact materialized-f32 path).  An engine with the
-    ``supports_fold`` capability receives ``Z`` and returns the zl
-    contraction from the same fused launch — on the oracle path that
-    fold is the identical einsum, so the default-path bitwise contract
-    is untouched.
+    default to the exact materialized-f32 path).  Under the factored
+    operator the engine's ``factored`` form applies K through ``Z`` and
+    ``a`` and returns the zl contraction with the duals.  An engine
+    with the ``supports_fold`` capability receives ``Z`` and returns
+    the zl contraction from the same fused launch — on the oracle path
+    that fold is the identical einsum, so the default-path bitwise
+    contract is untouched.
     """
     p = prob.X.shape[-1]
     if nbr_reduce is None:
@@ -117,9 +156,8 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
     # engines): the qp_solve phase
     with obs_spans.scope("qp_solve"):
         if qp_operator == "factored":
-            lam, zl = qp_engines.solve_factored_multi(
-                Z, inv.a, q, inv.hi, state.lam, iters=qp_iters,
-                L=inv.L)                                       # eq. (6)
+            lam, zl = engine.factored(Z, inv.a, q, inv.hi, state.lam,
+                                      iters=qp_iters, L=inv.L)  # eq. (6)
         elif getattr(engine, "supports_fold", False):
             lam, zl = engine(inv.K, q, inv.hi, state.lam, iters=qp_iters,
                              L=inv.L, precision=qp_precision,
@@ -314,7 +352,8 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
                     qp_operator: Optional[str] = None,
                     nbr_reduce: Optional[Callable] = None,
                     nbr_counts=None,
-                    budget: Optional[inv_lib.PlanBudget] = None) -> Plan:
+                    budget: Optional[inv_lib.PlanBudget] = None,
+                    backend: str = "vmap") -> Plan:
     """Precompute every loop-invariant of Prop. 1 into a ``Plan``.
 
     Parameters
@@ -336,11 +375,15 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
         ``supports_precision`` capability (``"pallas_fused_multi"``).
         Validated by risk deltas (BENCH_fit), never claimed bitwise.
     qp_operator : str, optional
-        ``"materialized"`` (default) or ``"factored"`` — the low-rank
-        O(N D) matvec ``Z (a (Z^T lam))``; K is never built (the
-        invariants carry ``K=None`` and the Gershgorin bound streams
-        through discarded row panels).  Requires
-        ``qp_solver="pallas_fused_multi"`` and f32.
+        ``"materialized"`` or ``"factored"`` — the low-rank O(N D)
+        matvec ``Z (a (Z^T lam))``; K is never built (the invariants
+        carry ``K=None`` and the Gershgorin bound streams through
+        discarded row panels).  Factored needs an engine with a
+        factored form (``"fista"``, ``"pallas_fused_multi"``), f32 and
+        a dense X.  ``None`` (the default) chooses from the shapes
+        (:func:`resolve_qp_operator`); each plan that resolves to
+        factored adds 1 to the ``plan.factored_plans`` counter
+        (``repro.obs.counters``).
     nbr_reduce : callable, optional
         Neighbor-sum hook for SPMD execution.
     nbr_counts : jnp.ndarray, optional
@@ -350,6 +393,10 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
         Memory budget for the K build: streams the Gram construction
         through bounded row panels instead of one batched matmul —
         bitwise identical to the dense build (the large-n scale path).
+    backend : str, optional
+        The backend that will step the plan (default ``"vmap"``; the
+        ``"async"`` fabric steps the materialized operator only), for
+        :func:`resolve_qp_operator`.
 
     Returns
     -------
@@ -365,13 +412,17 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
     if qp_precision is None:
         qp_precision = getattr(cfg, "qp_precision", "f32")
     if qp_operator is None:
-        qp_operator = getattr(cfg, "qp_operator", "materialized")
+        qp_operator = getattr(cfg, "qp_operator", None)
     if budget is None:
         budget = getattr(cfg, "budget", None)
     engine = qp_engines.get(qp_solver)   # fail fast on unknown engines
     if qp_precision not in ("f32", "bf16"):
         raise ValueError(f"unknown qp_precision {qp_precision!r}; "
                          f"expected 'f32' or 'bf16'")
+    qp_operator = resolve_qp_operator(
+        qp_operator, qp_solver=qp_solver, qp_precision=qp_precision,
+        x_shape=prob.X.shape,
+        sparse_x=isinstance(prob.X, sparse.SparseRows), backend=backend)
     if qp_operator not in ("materialized", "factored"):
         raise ValueError(f"unknown qp_operator {qp_operator!r}; "
                          f"expected 'materialized' or 'factored'")
@@ -381,10 +432,10 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
             f"qp_precision={qp_precision!r} needs a mixed-precision "
             f"engine (qp_solver='pallas_fused_multi'); got {qp_solver!r}")
     if qp_operator == "factored":
-        if not getattr(engine, "supports_fold", False):
+        if getattr(engine, "factored", None) is None:
             raise ValueError(
-                f"qp_operator='factored' is validated only with the "
-                f"fused multi engine (qp_solver='pallas_fused_multi'); "
+                f"qp_operator='factored' needs an engine with a factored "
+                f"form (qp_solver='fista' or 'pallas_fused_multi'); "
                 f"got {qp_solver!r}")
         if qp_precision != "f32":
             raise ValueError("qp_operator='factored' is f32-only "
@@ -396,6 +447,8 @@ def compile_problem(prob: core.DTSVMProblem, cfg=None, *,
         sparse.require_dense(prob.X, f"qp_solver={qp_solver!r} with "
                              f"qp_precision={qp_precision!r} and "
                              f"qp_operator={qp_operator!r}")
+    if qp_operator == "factored":
+        obs_counters.count("plan.factored_plans", 1)
     inv = inv_lib.compute_invariants(
         prob, nbr_counts=nbr_counts, budget=budget,
         materialize_k=(qp_operator != "factored"))

@@ -33,13 +33,16 @@ Built-ins:
                      oracle path is clip + fori of the single step —
                      bitwise identical to ``"pallas_fused"``.
 
-``solve_factored_multi`` (module-level, not registered — it consumes
-``(Z, a)`` instead of ``K``) is the low-rank companion: the same PG
-iteration with the matvec evaluated as ``Z (a * (Z^T lam))`` in
-O(N D) per step, K never materialized.  Selected via
-``SolverConfig(qp_operator="factored")``; validated against the
-materialized path by risk deltas, not bitwise (the contraction order
-differs by construction).
+An engine's ``factored`` attribute is its low-rank form, which
+consumes ``(Z, a)`` instead of ``K`` and evaluates each matvec as
+``Z (a * (Z^T lam))`` in O(N D), K never materialized; it returns
+``(lam, Z^T lam)``.  ``"fista"`` has one (``solve_fista_factored``),
+and so has ``"pallas_fused_multi"`` (``solve_factored_multi``, the same
+PG iteration).  ``compile_problem`` picks the operator from the shapes
+(``engine.plan.resolve_qp_operator``) or takes
+``SolverConfig(qp_operator=...)``.  Factored results match the
+materialized path to float32 tolerance, not bitwise (the contraction
+order differs by construction).
 
 Register new engines with ``@qp_engines.register("name")``; select per
 fit via ``SolverConfig(qp_solver="name")``.
@@ -103,6 +106,29 @@ def solve_fista(K, q, hi, lam0=None, *, iters: int,
     return _vmapped(qp_lib.solve_box_qp_fista, K, q, hi, lam0, L, iters)
 
 
+def solve_fista_factored(Z, a, q, hi, lam0=None, *, iters: int, L):
+    """:func:`solve_fista` on K = Z diag(a) Z^T applied through its
+    factors (``core.qp.solve_box_qp_fista_factored``, vmapped over the
+    batch dims): two reads of Z a step instead of one of K.  Returns
+    ``(lam, zl)`` with ``zl = Z^T lam``, as :func:`solve_factored_multi`
+    does."""
+    if lam0 is None:
+        lam0 = jnp.zeros_like(q)
+    fn = lambda Zb, ab, qb, hb, l0, Lb: qp_lib.solve_box_qp_fista_factored(
+        Zb, ab, qb, hb, iters, l0, L=Lb)
+    for _ in range(q.ndim - 1):
+        fn = jax.vmap(fn)
+    lam = fn(Z, a, q, hi, lam0, L)
+    # repro: noqa[raw-einsum-in-plan] — deliberate: plan_step's zl einsum, the same formula solve_factored_multi ends with
+    zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
+    return lam, zl
+
+
+#: the engine's factored form (``Z``, ``a`` in place of K), which
+#: ``plan_step`` runs under ``qp_operator="factored"``
+solve_fista.factored = solve_fista_factored
+
+
 @register("pg")
 def solve_pg(K, q, hi, lam0=None, *, iters: int,
              L: Optional[jnp.ndarray] = None):
@@ -156,10 +182,10 @@ solve_pallas_fused_multi.supports_fold = True
 def solve_factored_multi(Z, a, q, hi, lam0=None, *, iters: int, L):
     """The low-rank PG solve: K = Z diag(a) Z^T is rank <= D << N, so
     each matvec evaluates as ``Z (a * (Z^T lam))`` in O(N D) — K is
-    never materialized (``compile_problem`` skips the Gram build
-    entirely under ``qp_operator="factored"``; ``L`` is mandatory
-    because there is no K to derive it from — the invariant build
-    streams |K| row sums without keeping the panels).
+    never materialized (``compile_problem`` skips the Gram build under
+    ``qp_operator="factored"``; ``L`` is mandatory because there is no
+    K to derive it from — the invariant build streams |K| row sums
+    without keeping the panels).
 
     Returns ``(lam, zl)`` — the final-iterate w-update contraction
     falls out of the last factored matvec's inner product for free.
@@ -172,7 +198,7 @@ def solve_factored_multi(Z, a, q, hi, lam0=None, *, iters: int, L):
     lam = jnp.clip(lam0, 0.0, hi)
 
     def body(_, lam):
-        # repro: noqa[raw-einsum-in-plan] — deliberate: the factored operator's defining contraction; the mode is opt-in and validated by risk deltas, never claimed bitwise vs the materialized plan
+        # repro: noqa[raw-einsum-in-plan] — deliberate: the factored operator's defining contraction; validated to float32 tolerance, never claimed bitwise vs the materialized plan
         zt = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
         # repro: noqa[raw-einsum-in-plan] — deliberate: second half of the O(ND) factored matvec (see above)
         Klam = jnp.einsum("...nd,...d->...n", Z, a * zt, precision="highest")
@@ -182,3 +208,6 @@ def solve_factored_multi(Z, a, q, hi, lam0=None, *, iters: int, L):
     # repro: noqa[raw-einsum-in-plan] — deliberate: same formula as plan_step's zl einsum (the factored fold reuses the final Z^T lam)
     zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
     return lam, zl
+
+
+solve_pallas_fused_multi.factored = solve_factored_multi   # its PG form

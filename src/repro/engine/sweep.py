@@ -390,7 +390,7 @@ def compile_sweep(prob: core.DTSVMProblem, cfgs: Sequence, *,
     for key, default in (("qp_precision", "f32"),
                          ("qp_operator", "materialized")):
         bad = {getattr(c, key) for c in cfgs
-               if getattr(c, key, default) != default}
+               if getattr(c, key, default) not in (None, default)}
         if bad:
             raise ValueError(
                 f"compile_sweep shares one stacked materialized-K build; "

@@ -141,7 +141,7 @@ def run_async(prob: core.DTSVMProblem, iters: int, *,
     if plan is None:
         plan = engine_plan.compile_problem(prob, qp_iters=qp_iters,
                                            qp_solver=qp_solver,
-                                           budget=budget)
+                                           budget=budget, backend="async")
     if state is None:
         state = core.init_state(prob)
     V = prob.X.shape[0]

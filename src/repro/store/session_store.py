@@ -39,7 +39,7 @@ import numpy as np
 
 from repro import checkpoint
 from repro.api.session import OnlineSession
-from repro.api.solvers import SolverConfig
+from repro.api.solvers import SolverConfig, effective_backend
 from repro.core import dtsvm as core
 from repro.engine import plan as engine_plan
 from repro.net import elastic as elastic_lib
@@ -169,7 +169,8 @@ def _restore_session(tree: Any, *, check_fingerprint: bool
     pl = tree["plan"]
     if pl is not None:
         plan = engine_plan.compile_problem(
-            _problem_for(sess, pl["active"], pl["couple"]), cfg)
+            _problem_for(sess, pl["active"], pl["couple"]), cfg,
+            backend=effective_backend(cfg))
         if check_fingerprint and plan.fingerprint() != pl["fingerprint"]:
             raise schema.SchemaError(
                 "rebuilt plan fingerprint does not match the snapshot — "

@@ -507,11 +507,107 @@ def test_compile_problem_validates_qp_modes():
         engine.compile_problem(prob, qp_solver="fista",
                                qp_precision="bf16")
     with pytest.raises(ValueError, match="factored"):
-        engine.compile_problem(prob, qp_solver="fista",
+        engine.compile_problem(prob, qp_solver="pg",
                                qp_operator="factored")
     with pytest.raises(ValueError, match="factored"):
         engine.compile_problem(prob, qp_solver="pallas_fused_multi",
                                qp_precision="bf16", qp_operator="factored")
+
+
+@pytest.mark.parametrize("budget", [None, inv_lib.PlanBudget(max_elems=64)])
+def test_factored_fista_matches_materialized_and_legacy(budget):
+    """qp_solver="fista" with qp_operator="factored": K is never built,
+    L is bitwise the materialized plan's (budgeted or not), and the
+    state lands within float32 tolerance of the materialized FISTA fit
+    and of the legacy ``dtsvm_step`` scan."""
+    data, A = _make(V=4, T=2, n=12, seed=3)
+    prob = core.make_problem(data["X"], data["y"], data["mask"], A, C=0.01)
+    kw = dict(qp_iters=40, qp_solver="fista", budget=budget)
+    plan_m = engine.compile_problem(prob, qp_operator="materialized", **kw)
+    plan_f = engine.compile_problem(prob, qp_operator="factored", **kw)
+    assert plan_f.inv.K is None and plan_m.inv.K is not None
+    np.testing.assert_array_equal(np.asarray(plan_f.inv.L),
+                                  np.asarray(plan_m.inv.L))
+    st_m, _ = plan_m.run(iters=6)
+    st_f, _ = plan_f.run(iters=6)
+    _assert_states_close(st_f, st_m, rtol=2e-5)
+    _assert_states_close(st_f, _legacy_run(prob, 6, 40), rtol=2e-5)
+
+
+_FISTA = dict(qp_solver="fista", qp_precision="f32")
+
+
+@pytest.mark.parametrize("kw,want", [
+    (dict(x_shape=(20, 2, 40, 10), **_FISTA), "materialized"),    # paper
+    (dict(x_shape=(4, 2, 12000, 784), **_FISTA), "factored"),     # large_n
+    (dict(x_shape=(4, 2, 12000, 784), qp_solver="pallas_fused_multi",
+          qp_precision="f32"), "factored"),
+    (dict(x_shape=(4, 2, 12000, 6000), **_FISTA), "materialized"),  # N<2D
+    (dict(x_shape=(4, 4, 5788, 47236), sparse_x=True, **_FISTA),
+     "materialized"),                                             # rcv1
+    (dict(x_shape=(4, 2, 12000, 784), sparse_x=True, **_FISTA),
+     "materialized"),
+    (dict(x_shape=(4, 2, 12000, 784), qp_solver="pg",
+          qp_precision="f32"), "materialized"),
+    (dict(x_shape=(4, 2, 12000, 784), qp_solver="pallas_fused_multi",
+          qp_precision="bf16"), "materialized"),
+    (dict(x_shape=(4, 2, 12000, 784), backend="shard_map", **_FISTA),
+     "materialized"),
+    (dict(x_shape=(4, 2, 12000, 784), backend="async", **_FISTA),
+     "materialized"),
+    (dict(x_shape=(2, 2, 24000, 784), backend="sample_shard", **_FISTA),
+     "materialized"),                                             # x4 cell
+    (dict(x_shape=(20, 2, 40, 10), qp_operator="factored", **_FISTA),
+     "factored"),
+    (dict(x_shape=(4, 2, 12000, 784), qp_operator="materialized",
+          **_FISTA), "materialized"),
+])
+def test_qp_operator_resolves_from_shape(kw, want):
+    from repro.engine import plan as plan_lib
+    kw = dict(kw)
+    assert plan_lib.resolve_qp_operator(kw.pop("qp_operator", None),
+                                        **kw) == want
+
+
+def test_qp_operator_floor_sits_on_k_bytes():
+    """Just below the floor a shape stays materialized, at it factored."""
+    from repro.engine import plan as plan_lib
+    floor = plan_lib.FACTORED_MIN_K_BYTES
+    n = int(np.ceil(np.sqrt(floor / (4 * 8))))        # V*T = 8
+    res = lambda N: plan_lib.resolve_qp_operator(
+        None, x_shape=(4, 2, N, 15), **_FISTA)
+    assert 4 * 8 * (n - 1) ** 2 < floor <= 4 * 8 * n * n
+    assert (res(n - 1), res(n)) == ("materialized", "factored")
+
+
+def test_factored_plans_counter_and_no_lowering_on_second_fit(monkeypatch):
+    """plan.factored_plans rises once per factored plan, never for a
+    materialized one; a second factored fit of the same shapes lowers
+    nothing."""
+    from repro import obs
+    from repro.engine import plan as plan_lib
+    data, A = _make(V=4, T=2, n=30, seed=5)          # N > 2 (p+1)
+    data2, _ = _make(V=4, T=2, n=30, seed=6)
+    read = lambda: obs.read_counters().get("plan.factored_plans", 0)
+    cfg = SolverConfig(C=0.01, iters=3, qp_iters=10)
+    n0 = read()
+    DTSVM(cfg).fit(data["X"], data["y"], mask=data["mask"], adj=A)
+    assert read() == n0                                 # materialized
+    # the floor at this toy size: the same fit now resolves to factored
+    monkeypatch.setattr(plan_lib, "FACTORED_MIN_K_BYTES", 1)
+    fitted = DTSVM(cfg).fit(data["X"], data["y"], mask=data["mask"], adj=A)
+    assert read() == n0 + 1
+    obs.clear_compiles()
+    DTSVM(cfg).fit(data2["X"], data2["y"], mask=data2["mask"], adj=A)
+    assert read() == n0 + 2
+    lowered = {prog: ph["lower"]["count"]
+               for prog, ph in obs.compile_counts()["programs"].items()
+               if "lower" in ph}
+    assert lowered == {}
+    explicit = DTSVM(cfg.replace(qp_operator="factored")).fit(
+        data["X"], data["y"], mask=data["mask"], adj=A)
+    assert read() == n0 + 3
+    _assert_states_equal(fitted.state_, explicit.state_)
 
 
 def test_backends_run_gates_qp_modes_to_vmap():
