@@ -72,9 +72,8 @@ HOT_ROOTS = frozenset({
     "apply_membership",
     "solve_fista", "solve_fista_factored", "solve_pg", "solve_pallas_fused",
     "solve_pallas_fused_multi", "solve_factored_multi",
-    "solve_box_qp_pg", "solve_box_qp_fista", "solve_box_qp_fista_factored",
+    "solve_box_qp_pg", "solve_box_qp_fista",
     "weighted_gram", "weighted_gram_rows", "qp_pg_step", "qp_pg_multi",
-    "_qp_rows",
     "collect_diagnostics", "collect_shard_diagnostics",
 })
 

@@ -31,15 +31,17 @@ compiled once per fit, then the light per-iteration body runs.
 
 - ``"sample_shard"`` a node's local samples split across devices (the
                    large-n path, API.md §scale): each device owns an
-                   N/S row panel of every (v,t) dual Hessian, the QP
-                   iterates with panel matvecs + one all-gather of the
-                   iterate per step, and the dual linear term reduces
-                   across the sample axis (``reduce="gather"`` is
+                   N/S row panel of every (v,t) dual Hessian; the
+                   ``row_panels`` hook of ``plan_step`` all-gathers the
+                   iterate per QP step and reduces the dual linear
+                   term across the sample axis (``reduce="gather"`` is
                    bitwise the vmap fit; ``"psum"`` is the cheap
                    equivalent).  Accepts ``n_shards=`` / ``mesh=`` and
                    a ``budget=`` for streamed panel builds.
 
-All are numerically equivalent in their lossless configurations — the
+Every backend steps the one ``engine.plan_step``, in a build program
+and a scan program compiled once per shape; they differ in where the
+invariants live and in the step's hooks.  All are numerically equivalent in their lossless configurations — the
 async backend's identity fabric and the sample-sharded gather mode are
 bitwise the vmap path (tested); pick by config, not by import.
 ``qp_solver`` selects the inner dual engine ("fista" | "pg" |
@@ -48,11 +50,19 @@ bitwise the vmap path (tested); pick by config, not by import.
 """
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 from repro.core import dtsvm as core
 from repro.core import dtsvm_dist, sparse
+from repro.dist import sharding as shard_lib
+from repro.engine import invariants as inv_lib
 from repro.engine import plan as engine_plan
+from repro.kernels import ops as kops
 from repro.net import async_admm
 from repro.obs import spans as obs_spans
 from repro.obs import telemetry as obs_telemetry
@@ -189,7 +199,6 @@ def _run_shard_map(prob: core.DTSVMProblem, iters: int, *,
     st = core.init_state(prob) if state is None else state
     hi = None
     if telemetry is not None:
-        from repro.engine import invariants as inv_lib
         hi = inv_lib._masks_part(prob)[4]
     hist, tel_rows = [], []
     for _ in range(iters):
@@ -200,12 +209,10 @@ def _run_shard_map(prob: core.DTSVMProblem, iters: int, *,
         if telemetry is not None:
             tel_rows.append(telemetry.collect(prob, hi, st, prev))
     if telemetry_out is not None and tel_rows:
-        import numpy as np
         telemetry_out["streams"] = {
             k: np.stack([np.asarray(row[k], np.float32)
                          for row in tel_rows])
             for k in tel_rows[0]}
-    import jax.numpy as jnp
     return st, (jnp.stack(hist) if eval_fn is not None else None)
 
 
@@ -248,48 +255,103 @@ def _run_async(prob: core.DTSVMProblem, iters: int, *, qp_iters: int = 200,
     return res.state, res.history
 
 
-def _qp_rows(K_rows, q_rows, hi_rows, lam0_rows, L, *, iters: int,
-             axis: str, qp_solver: str):
-    """The dual box-QP iterated on a row panel of each (v,t) Hessian.
+def _panel_specs(axis: str):
+    """``(prob, state, invariants)`` specs of the sample-sharded plan:
+    every N-sized leaf splits over ``axis``, the rest replicate."""
+    prob_spec, state_spec = shard_lib.sample_specs(axis)
+    repl = shard_lib.P()
+    return prob_spec, state_spec, inv_lib.PlanInvariants(
+        ntp=repl, nbr=repl, u=repl, a=repl, Z=prob_spec.X, K=prob_spec.X,
+        hi=prob_spec.y, L=repl)
 
-    Mirrors ``core.qp.solve_box_qp_fista`` / ``_pg`` operation for
-    operation on the shard's rows: each iteration all-gathers the
-    (V, T, N) iterate across the sample axis (tiled — exact
-    concatenation), applies the local K[rows, :] row-block of the
-    matvec, and updates the local rows elementwise.  Every per-element
-    float op matches the dense solver's, so the sharded QP is bitwise
-    the dense one (tests/test_scale.py).
-    """
-    import jax
-    import jax.numpy as jnp
 
-    step = 1.0 / L                                        # (V, T)
-    matvec = jax.vmap(jax.vmap(                          # rows of K @ y
-        lambda Kr, yf: jnp.matmul(Kr, yf, precision="highest")))
-    def gather(y):
-        with obs_spans.scope("neighbour_exchange"):
-            return jax.lax.all_gather(y, axis, axis=2, tiled=True)
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "budget",
+                                             "_pallas"))
+def _panel_build(prob, *, mesh, axis, budget, _pallas):
+    """Each device's invariants as one cached program: counts/u/a
+    replicated, Z/hi/K as its row panels, L the global Gershgorin bound;
+    plus Z over all samples (``reduce="gather"`` contracts the gathered
+    duals with it).  ``_pallas`` keys the cache on the kernel switch
+    ``weighted_gram_rows`` reads at trace time."""
+    prob_spec, _, inv_spec = _panel_specs(axis)
 
-    lam = jnp.clip(lam0_rows, 0.0, hi_rows)
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=(prob_spec,),
+                       out_specs=(inv_spec, shard_lib.P()), check_vma=False)
+    def build(pr):
+        with obs_spans.scope("invariant_build"):
+            ntp, nbr, u, a, hi = inv_lib._masks_part(pr)
+            Z = inv_lib.compute_z(pr)                      # (V,T,Nl,p+1)
+            with obs_spans.scope("neighbour_exchange"):
+                Z_full = jax.lax.all_gather(Z, axis, axis=2, tiled=True)
+            V, T, Nl = Z.shape[:3]
+            chunk = None if budget is None else \
+                budget.row_chunk(V * T, Nl, cols=Z_full.shape[2])
+            tile = None if budget is None else budget.tile
+            if chunk is None:
+                K = kops.weighted_gram_rows(Z, a, Z_full, tile=tile)
+                rs = jnp.sum(jnp.abs(K), axis=-1)
+            else:
+                K, rs = inv_lib.streamed_gram_panel(Z, a, Z_full, chunk, tile)
+            # global Gershgorin bound: max over ALL rows (max is exact)
+            with obs_spans.scope("neighbour_exchange"):
+                L = jnp.maximum(jax.lax.pmax(jnp.max(rs, axis=-1), axis),
+                                1e-12)
+        return inv_lib.PlanInvariants(ntp=ntp, nbr=nbr, u=u, a=a, Z=Z, K=K,
+                                      hi=hi, L=L), Z_full
 
-    if qp_solver == "pg":
-        def body(_, lam):
-            grad = q_rows - matvec(K_rows, gather(lam))
-            return jnp.clip(lam + step[..., None] * grad, 0.0, hi_rows)
+    return build(prob)
 
-        return jax.lax.fori_loop(0, iters, body, lam)
 
-    def body(_, s):                                       # fista
-        lam, y, t = s
-        grad = q_rows - matvec(K_rows, gather(y))
-        lam_new = jnp.clip(y + step[..., None] * grad, 0.0, hi_rows)
-        t_new = 0.5 * (1.0 + jnp.sqrt(1.0 + 4.0 * t * t))
-        y_new = lam_new + ((t - 1.0) / t_new) * (lam_new - lam)
-        return (lam_new, y_new, t_new)
+def _panel_scan(state, prob, inv, Z_full, *, mesh, axis, iters, qp_iters,
+                qp_solver, reduce, telemetry, eval_fn=None):
+    """``iters`` ``plan_step``s on each device's row panels, the
+    sample-axis collectives as its ``row_panels`` hook."""
+    prob_spec, state_spec, inv_spec = _panel_specs(axis)
 
-    lam, _, _ = jax.lax.fori_loop(0, iters, body,
-                                  (lam, lam, jnp.float32(1.0)))
-    return lam
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(state_spec, prob_spec, inv_spec,
+                                 shard_lib.P()),
+                       out_specs=(state_spec, shard_lib.P()),
+                       check_vma=False)
+    def scan(st, pr, iv, Z_full):
+        def gather(y):              # local rows -> all rows, exact concat
+            with obs_spans.scope("neighbour_exchange"):
+                return jax.lax.all_gather(y, axis, axis=y.ndim - 1,
+                                          tiled=True)
+
+        def zl(lam, Z):
+            if reduce == "gather":
+                return jnp.einsum("vtn,vtnd->vtd", gather(lam), Z_full,
+                                  precision="highest")
+            part = jnp.einsum("vtn,vtnd->vtd", lam, Z, precision="highest")
+            with obs_spans.scope("neighbour_exchange"):
+                return jax.lax.psum(part, axis)
+
+        panels = engine_plan.RowPanels(gather=gather, zl=zl)
+
+        def body(s, _):
+            with obs_spans.scope("scan_execute"):
+                new = engine_plan.plan_step(pr, iv, s, qp_iters=qp_iters,
+                                            qp_solver=qp_solver,
+                                            row_panels=panels)
+                out = (eval_fn(new) if eval_fn is not None
+                       else jnp.float32(0))
+                # None is an empty pytree node: telemetry-off scans carry
+                # exactly the original outputs (bitwise contract)
+                tel = (None if telemetry is None
+                       else obs_telemetry.collect_shard_diagnostics(
+                           pr, iv.hi, new, s, telemetry.streams, axis))
+            return new, (out, tel)
+
+        return jax.lax.scan(body, st, None, length=iters)
+
+    return scan(state, prob, inv, Z_full)
+
+
+#: the scan as one cached program, keyed on the shapes and the static
+#: configuration (``engine.plan._plan_scan``'s counterpart)
+_panel_scan_jit = jax.jit(_panel_scan, static_argnames=(
+    "mesh", "axis", "iters", "qp_iters", "qp_solver", "reduce", "telemetry"))
 
 
 @register("sample_shard")
@@ -306,9 +368,12 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
     and builds ONLY its row panel K[rows, :] of every (v,t) dual Hessian
     (``kernels.ops.weighted_gram_rows``, optionally streamed under
     ``budget``) — per-device Gram memory drops from N² to N²/S.  The
-    dual QP iterates with the panel matvec plus one all-gather of the
-    (V, T, N) iterate per inner step; the O(p)-sized consensus math
-    (r/alpha/beta updates, neighbor sums) is replicated.
+    scan steps ``engine.plan_step`` on those panels with the sample-axis
+    collectives as its ``row_panels`` hook: one all-gather of the
+    (V, T, N) iterate per inner QP step, and the dual linear term
+    reduced across the sample axis; the O(p)-sized consensus math is
+    replicated.  Build and scan are cached programs (``_panel_build``,
+    ``_panel_scan_jit``); a call with an ``eval_fn`` traces anew.
 
     Parameters
     ----------
@@ -338,15 +403,6 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
     partial sums combined with one psum
     (``obs.telemetry.collect_shard_diagnostics``).
     """
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.dist import sharding as shard_lib
-    from repro.engine import invariants as inv_lib
-    from repro.kernels import ops as kops
-
     if qp_solver not in ("fista", "pg"):
         raise ValueError(
             f"sample_shard supports qp_solver 'fista' | 'pg', got "
@@ -355,88 +411,28 @@ def _run_sample_shard(prob: core.DTSVMProblem, iters: int, *,
     if reduce not in ("gather", "psum"):
         raise ValueError(f"unknown reduce {reduce!r}; "
                          f"expected 'gather' or 'psum'")
-    V, T, N, p = prob.X.shape
+    N = prob.X.shape[2]
     if mesh is None:
         mesh = shard_lib.make_sample_mesh(N, n_shards, axis=axis)
     n_dev = dict(zip(mesh.axis_names, mesh.devices.shape))[axis]
     if N % n_dev:
         raise ValueError(f"{N} samples do not tile evenly over {n_dev} "
                          f"'{axis}' devices")
-    prob_spec, state_spec = shard_lib.sample_specs(axis)
-    tile = None if budget is None else budget.tile
-
-    @functools.partial(jax.shard_map, mesh=mesh,
-                       in_specs=(state_spec, prob_spec),
-                       out_specs=(state_spec, shard_lib.P()),
-                       check_vma=False)
-    def run_shard(st, pr):
-        # -- invariants: counts/u/a replicated, Z/hi/K as row panels --
-        with obs_spans.scope("invariant_build"):
-            ntp, nbr, u, a, hi_rows = inv_lib._masks_part(pr)
-            Z_rows = inv_lib.compute_z(pr)                # (V,T,Nl,p+1)
-            with obs_spans.scope("neighbour_exchange"):
-                Z_full = jax.lax.all_gather(Z_rows, axis, axis=2,
-                                            tiled=True)
-            Nl = Z_rows.shape[2]
-            chunk = None if budget is None else \
-                budget.row_chunk(V * T, Nl, cols=N)
-            if chunk is None:
-                K_rows = kops.weighted_gram_rows(Z_rows, a, Z_full,
-                                                 tile=tile)
-                rs = jnp.sum(jnp.abs(K_rows), axis=-1)
-            else:
-                K_rows, rs = inv_lib.streamed_gram_panel(Z_rows, a, Z_full,
-                                                         chunk, tile)
-            # global Gershgorin bound: max over ALL rows (max is exact)
-            with obs_spans.scope("neighbour_exchange"):
-                rs_max = jax.lax.pmax(jnp.max(rs, axis=-1), axis)
-            L = jnp.maximum(rs_max, 1e-12)
-        nbr_reduce = core._default_nbr_reduce(pr)
-
-        def step(s):
-            # mirrors engine.plan_step, with the N-sized pieces sharded
-            f = core._f_vec(pr, s, ntp, nbr, nbr_reduce)
-            g = f[..., : p + 1] / u[..., : p + 1] \
-                + f[..., p + 1:] / u[..., p + 1:]
-            q_rows = pr.mask + jnp.sum(Z_rows * g[..., None, :], axis=-1)
-            with obs_spans.scope("qp_solve"):
-                lam = _qp_rows(K_rows, q_rows, hi_rows, s.lam, L,
-                               iters=qp_iters, axis=axis,
-                               qp_solver=qp_solver)
-                if reduce == "gather":
-                    with obs_spans.scope("neighbour_exchange"):
-                        lam_full = jax.lax.all_gather(lam, axis, axis=2,
-                                                      tiled=True)
-                    zl = jnp.einsum("vtn,vtnd->vtd", lam_full, Z_full,
-                                    precision="highest")
-                else:
-                    zl_rows = jnp.einsum("vtn,vtnd->vtd", lam, Z_rows,
-                                         precision="highest")
-                    with obs_spans.scope("neighbour_exchange"):
-                        zl = jax.lax.psum(zl_rows, axis)
-            r_new, alpha, beta = engine_plan.consensus_update(
-                pr, s, u, ntp, nbr, f, zl, nbr_reduce)
-            return core.DTSVMState(r=r_new, alpha=alpha, beta=beta, lam=lam)
-
-        def body(s, _):
-            with obs_spans.scope("scan_execute"):
-                new = step(s)
-                out = (eval_fn(new) if eval_fn is not None
-                       else jnp.float32(0))
-                # None is an empty pytree node: telemetry-off scans carry
-                # exactly the original outputs (bitwise contract)
-                tel = (None if telemetry is None
-                       else obs_telemetry.collect_shard_diagnostics(
-                           pr, hi_rows, new, s, telemetry.streams, axis))
-            return new, (out, tel)
-
-        return jax.lax.scan(body, st, None, length=iters)
-
+    with obs_spans.span("invariant_build", budgeted=budget is not None):
+        inv, Z_full = _panel_build(prob, mesh=mesh, axis=axis,
+                                   budget=budget, _pallas=kops._use_pallas())
     if state is None:
         state = core.init_state(prob)
-    st, (hist, tel_streams) = jax.jit(run_shard)(state, prob)
-    if telemetry_out is not None and tel_streams is not None:
-        telemetry_out["streams"] = obs_telemetry.materialize(tel_streams)
+    kw = dict(mesh=mesh, axis=axis, iters=int(iters), qp_iters=qp_iters,
+              qp_solver=qp_solver, reduce=reduce, telemetry=telemetry)
+    if eval_fn is None:
+        scan = functools.partial(_panel_scan_jit, **kw)
+    else:                         # a per-call closure: traced anew, uncached
+        scan = jax.jit(functools.partial(_panel_scan, eval_fn=eval_fn, **kw))
+    with obs_spans.span("scan_execute", iters=int(iters)):
+        st, (hist, tel) = scan(state, prob, inv, Z_full)
+    if telemetry_out is not None and tel is not None:
+        telemetry_out["streams"] = obs_telemetry.materialize(tel)
     return st, (hist if eval_fn is not None else None)
 
 

@@ -15,22 +15,26 @@ sum becomes a collective (DESIGN.md §3 hardware mapping):
 Both reuse the exact Prop.-1 math via the ``nbr_reduce`` hook, so the SPMD
 run is numerically identical to the single-host reference (tested).
 
-Execution shards the *plan* (repro.engine): each node compiles its local
-loop-invariants (Z, K, u, counts, box, step size) ONCE inside the
-shard_map region, then scans the light state-dependent iteration — the
-Hessian is never rebuilt per iteration per node.
+Execution shards the *plan* (repro.engine): ``_node_build`` computes
+each node's loop-invariants (Z, K, u, counts, box, step size) once, and
+``_node_scan`` scans ``engine.plan_step`` against them.  Both are
+module-level jits keyed on the shapes and the static configuration, so
+a second run of one shape lowers nothing.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import dtsvm
+from repro.engine import invariants as inv_lib
+from repro.engine import plan as engine_plan
+from repro.kernels import ops as kops
 
 
 def make_node_mesh(V: int, axis: str = "nodes") -> Mesh:
@@ -57,34 +61,8 @@ def _nbr_reduce_for(adjf, *, axis: str, topology: str):
     return nbr_reduce
 
 
-def _shard_run(state, prob, adj_rows, active_global, *, axis: str,
-               topology: str, qp_iters: int, iters: int,
-               qp_solver: str = "fista", budget=None):
-    """``iters`` planned ADMM iterations on (V_local, ...) shards inside
-    shard_map: invariants compile once per node, then the light
-    ``engine.plan_step`` body scans — never rebuilding the Hessian."""
-    from repro.engine import invariants as inv_lib
-    from repro.engine import plan as engine_plan
-
-    adjf = adj_rows.astype(jnp.float32)                      # (Vl, V)
-    nbr_reduce = _nbr_reduce_for(adjf, axis=axis, topology=topology)
-    nbr_counts = jnp.einsum("vu,ut->vt", adjf, active_global)
-    inv = inv_lib.compute_invariants(prob, nbr_counts=nbr_counts,
-                                     budget=budget)
-
-    def body(st, _):
-        st = engine_plan.plan_step(prob, inv, st, qp_iters=qp_iters,
-                                   qp_solver=qp_solver,
-                                   nbr_reduce=nbr_reduce)
-        return st, None
-
-    state, _ = jax.lax.scan(body, state, None, length=iters)
-    return state
-
-
 def _node_specs(axis: str):
     """Sharding specs: state/problem/invariants over the node axis."""
-    from repro.engine import invariants as inv_lib
     node = P(axis)
     repl = P()
     state_spec = dtsvm.DTSVMState(r=node, alpha=node, beta=node, lam=node)
@@ -100,37 +78,52 @@ def _node_specs(axis: str):
     return node, repl, state_spec, prob_spec, inv_spec
 
 
-def build_runner(mesh: Mesh, *, axis: str = "nodes",
-                 topology: str = "graph", qp_iters: int = 200,
-                 iters: int = 1, qp_solver: str = "fista", budget=None):
-    """A reusable jitted ``run(state, prob) -> state`` executing ``iters``
-    decentralized ADMM iterations on ``mesh`` (invariants compiled once
-    per call inside the shard).
+@functools.partial(jax.jit, static_argnames=("mesh", "axis", "budget",
+                                             "_pallas"))
+def _node_build(prob, *, mesh, axis, budget, _pallas):
+    """Each node's plan invariants (its local K build) as one cached
+    program; ``_pallas`` keys the cache on the kernel switch the Gram
+    kernels read at trace time."""
+    node, repl, _, prob_spec, inv_spec = _node_specs(axis)
 
-    The returned callable has a stable identity, so calling it repeatedly
-    compiles ONCE and hits jax's jit cache afterwards — unlike re-invoking
-    ``run_dtsvm_dist``, which rebuilds its closures every call.  For
-    repeated SHORT calls against one problem (a host-evaluated risk
-    curve), use ``build_planned_runner`` instead so the invariants are
-    not recompiled on every call.
-    """
-    node, repl, state_spec, prob_spec, _ = _node_specs(axis)
-
-    @functools.partial(
-        jax.shard_map, mesh=mesh,
-        in_specs=(state_spec, prob_spec, node, repl),
-        check_vma=False, out_specs=state_spec)
-    def run_shard(st, pr, adj_r, act_g):
-        return _shard_run(st, pr, adj_r, act_g, axis=axis,
-                          topology=topology, qp_iters=qp_iters,
-                          iters=iters, qp_solver=qp_solver, budget=budget)
-
-    @jax.jit
-    def run(st, pr):
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(prob_spec, node, repl), out_specs=inv_spec,
+                       check_vma=False)
+    def build(pr, adj_r, act_g):
         # adj rows shard over nodes; the active table stays global
-        return run_shard(st, pr, pr.adj, pr.active)
+        nbr_counts = jnp.einsum("vu,ut->vt", adj_r.astype(jnp.float32), act_g)
+        return inv_lib.compute_invariants(pr, nbr_counts=nbr_counts,
+                                          budget=budget)
 
-    return run
+    return build(prob, prob.adj, prob.active)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "axis", "topology", "qp_iters", "iters", "qp_solver",
+    "_pallas"))
+def _node_scan(state, prob, inv, *, mesh, axis, topology, qp_iters, iters,
+               qp_solver, _pallas):
+    """``iters`` ``plan_step``s on each node's shard, the neighbour sum a
+    collective, as one cached program (keyed on ``_pallas`` as
+    ``engine.plan._plan_scan`` is: the Pallas engines read the switch at
+    trace time)."""
+    node, _, state_spec, prob_spec, inv_spec = _node_specs(axis)
+
+    @functools.partial(jax.shard_map, mesh=mesh,
+                       in_specs=(state_spec, prob_spec, inv_spec, node),
+                       out_specs=state_spec, check_vma=False)
+    def scan(st, pr, iv, adj_r):
+        nbr_reduce = _nbr_reduce_for(adj_r.astype(jnp.float32), axis=axis,
+                                     topology=topology)
+
+        def body(s, _):
+            return engine_plan.plan_step(pr, iv, s, qp_iters=qp_iters,
+                                         qp_solver=qp_solver,
+                                         nbr_reduce=nbr_reduce), None
+
+        return jax.lax.scan(body, st, None, length=iters)[0]
+
+    return scan(state, prob, inv, prob.adj)
 
 
 def build_planned_runner(mesh: Mesh, *, axis: str = "nodes",
@@ -141,48 +134,18 @@ def build_planned_runner(mesh: Mesh, *, axis: str = "nodes",
 
     ``inv = compile_fn(prob)`` builds the node-sharded plan invariants
     (one weighted-Gram Hessian build per fit); ``step_fn(state, prob,
-    inv)`` then advances ``iters`` ADMM iterations against them.  This
-    is the host-eval history path: per-iteration evaluation calls
-    ``step_fn`` repeatedly WITHOUT recompiling the invariants each time.
+    inv)`` then advances ``iters`` ADMM iterations against them (the
+    host-eval history path: ``iters=1`` between evaluations).  Both
+    dispatch the cached programs ``_node_build`` / ``_node_scan``.
     """
-    from repro.engine import invariants as inv_lib
-    from repro.engine import plan as engine_plan
-
-    node, repl, state_spec, prob_spec, inv_spec = _node_specs(axis)
-
-    @functools.partial(
-        jax.shard_map, mesh=mesh, in_specs=(prob_spec, node, repl),
-        check_vma=False, out_specs=inv_spec)
-    def compile_shard(pr, adj_r, act_g):
-        adjf = adj_r.astype(jnp.float32)
-        nbr_counts = jnp.einsum("vu,ut->vt", adjf, act_g)
-        return inv_lib.compute_invariants(pr, nbr_counts=nbr_counts,
-                                          budget=budget)
-
-    @functools.partial(
-        jax.shard_map, mesh=mesh,
-        in_specs=(state_spec, prob_spec, inv_spec, node),
-        check_vma=False, out_specs=state_spec)
-    def step_shard(st, pr, inv, adj_r):
-        adjf = adj_r.astype(jnp.float32)
-        nbr_reduce = _nbr_reduce_for(adjf, axis=axis, topology=topology)
-
-        def body(s, _):
-            s = engine_plan.plan_step(pr, inv, s, qp_iters=qp_iters,
-                                      qp_solver=qp_solver,
-                                      nbr_reduce=nbr_reduce)
-            return s, None
-
-        st, _ = jax.lax.scan(body, st, None, length=iters)
-        return st
-
-    @jax.jit
     def compile_fn(pr):
-        return compile_shard(pr, pr.adj, pr.active)
+        return _node_build(pr, mesh=mesh, axis=axis, budget=budget,
+                           _pallas=kops._use_pallas())
 
-    @jax.jit
     def step_fn(st, pr, inv):
-        return step_shard(st, pr, inv, pr.adj)
+        return _node_scan(st, pr, inv, mesh=mesh, axis=axis,
+                          topology=topology, qp_iters=qp_iters, iters=iters,
+                          qp_solver=qp_solver, _pallas=kops._use_pallas())
 
     return compile_fn, step_fn
 
@@ -192,13 +155,14 @@ def run_dtsvm_dist(prob: dtsvm.DTSVMProblem, iters: int,
                    topology: str = "graph", qp_iters: int = 200,
                    state: Optional[dtsvm.DTSVMState] = None,
                    qp_solver: str = "fista", budget=None):
-    """Decentralized run.  Shards every (V, ...) array over the node axis."""
+    """Decentralized run.  Shards every (V, ...) array over the node
+    axis: the invariant build, then ``iters`` iterations against it."""
     V = prob.X.shape[0]
     if mesh is None:
         mesh = make_node_mesh(V, axis)
     if state is None:
         state = dtsvm.init_state(prob)
-    run = build_runner(mesh, axis=axis, topology=topology,
-                       qp_iters=qp_iters, iters=iters, qp_solver=qp_solver,
-                       budget=budget)
-    return run(state, prob)
+    compile_fn, step_fn = build_planned_runner(
+        mesh, axis=axis, topology=topology, qp_iters=qp_iters, iters=iters,
+        qp_solver=qp_solver, budget=budget)
+    return step_fn(state, prob, compile_fn(prob))

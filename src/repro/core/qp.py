@@ -8,9 +8,12 @@ Solvers (all fixed-iteration ``jax.lax`` loops, jit/vmap-friendly):
 
 - ``solve_box_qp_pg``       projected-gradient ascent, Gershgorin step size
 - ``solve_box_qp_fista``    Nesterov-accelerated projected gradient
-- ``solve_box_qp_fista_factored``  the same on K = Z diag(a) Z^T, applied
-                            as ``Z (a (Z^T y))`` without building K
 - ``kkt_residual``          optimality measure used by tests
+
+Each algorithm is one recursion (``_pg``, ``_fista``) over a ``matvec``
+that applies K; every solver and engine (``engine.qp_engines``) runs
+these two, on K itself, on a row panel of K (the sample-sharded
+backend) or on the factors of K = Z diag(a) Z^T (``factored_matvec``).
 
 K is PSD by construction (a Gram matrix), so the Gershgorin row-sum bound
 dominates the spectral norm and 1/L steps are safe.  Both solvers accept
@@ -39,6 +42,20 @@ def _project(lam, hi):
     return jnp.clip(lam, 0.0, hi)
 
 
+def _pg(matvec, q, hi, iters, lam0, L):
+    """The projected-gradient recursion on one box QP, K applied by
+    ``matvec``."""
+    step = 1.0 / L
+    lam = jnp.zeros_like(q) if lam0 is None else lam0
+    lam = _project(lam, hi)
+
+    def body(_, lam):
+        grad = q - matvec(lam)
+        return _project(lam + step * grad, hi)
+
+    return jax.lax.fori_loop(0, iters, body, lam)
+
+
 def solve_box_qp_pg(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
                     iters: int = 200, lam0=None,
                     L: Optional[jnp.ndarray] = None) -> jnp.ndarray:
@@ -46,15 +63,8 @@ def solve_box_qp_pg(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
     precomputed Gershgorin bound)."""
     if L is None:
         L = gershgorin_lipschitz(K)
-    step = 1.0 / L
-    lam = jnp.zeros_like(q) if lam0 is None else lam0
-    lam = _project(lam, hi)
-
-    def body(_, lam):
-        grad = q - jnp.matmul(K, lam, precision="highest")
-        return _project(lam + step * grad, hi)
-
-    return jax.lax.fori_loop(0, iters, body, lam)
+    return _pg(lambda y: jnp.matmul(K, y, precision="highest"),
+               q, hi, iters, lam0, L)
 
 
 def _fista(matvec, q, hi, iters, lam0, L):
@@ -86,19 +96,15 @@ def solve_box_qp_fista(K: jnp.ndarray, q: jnp.ndarray, hi: jnp.ndarray,
                   q, hi, iters, lam0, L)
 
 
-def solve_box_qp_fista_factored(Z: jnp.ndarray, a: jnp.ndarray,
-                                q: jnp.ndarray, hi: jnp.ndarray,
-                                iters: int = 200, lam0=None, *,
-                                L: jnp.ndarray) -> jnp.ndarray:
-    """``solve_box_qp_fista`` on K = Z diag(a) Z^T (Z: (N, D), a: (D,))
-    applied through its factors, ``K y = Z (a * (Z^T y))`` in O(N D)
-    instead of O(N^2).  ``L`` is mandatory: there is no K to derive the
-    Gershgorin bound from."""
+def factored_matvec(Z: jnp.ndarray, a: jnp.ndarray):
+    """``y -> K y`` for K = Z diag(a) Z^T (Z: (N, D), a: (D,)) applied
+    through its factors, ``Z (a * (Z^T y))``: O(N D) instead of
+    O(N^2)."""
     def matvec(y):
         zt = jnp.matmul(y, Z, precision="highest")
         return jnp.matmul(Z, a * zt, precision="highest")
 
-    return _fista(matvec, q, hi, iters, lam0, L)
+    return matvec
 
 
 def qp_objective(K, q, lam):

@@ -26,7 +26,7 @@ from __future__ import annotations
 
 import functools
 import hashlib
-from typing import Callable, Optional
+from typing import Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -85,10 +85,7 @@ def consensus_update(prob: core.DTSVMProblem, state: core.DTSVMState,
 
     ``zl = X^T Y lam`` (V, T, p+1) summarizes the dual solve; everything
     else is precomputed invariants plus the carried state.  Returns
-    ``(r_new, alpha, beta)``.  Shared by ``plan_step`` and the
-    sample-sharded backend (whose duals live on row panels but whose
-    consensus math is replicated) — one copy of ops that must stay
-    bitwise-identical across execution paths.
+    ``(r_new, alpha, beta)``.
     """
     p = prob.X.shape[-1]
     with obs_spans.scope("consensus_update"):
@@ -112,16 +109,28 @@ def consensus_update(prob: core.DTSVMProblem, state: core.DTSVMState,
     return r_new, alpha, beta
 
 
+class RowPanels(NamedTuple):
+    """The sample-axis hook of :func:`plan_step`: the invariants, duals
+    and N-sized problem leaves hold one device's rows (K its row panel
+    K[rows, :]), and these collectives join the rows."""
+    gather: Callable   # iterate rows (..., N/S) -> (..., N), per matvec
+    zl: Callable       # (lam, Z) rows -> X^T Y lam over all samples
+
+
 def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
               state: core.DTSVMState, *, qp_iters: int = 200,
               qp_solver: str = DEFAULT_QP_SOLVER,
               qp_precision: str = "f32",
               qp_operator: str = "materialized",
-              nbr_reduce: Optional[Callable] = None) -> core.DTSVMState:
+              nbr_reduce: Optional[Callable] = None,
+              row_panels: Optional[RowPanels] = None) -> core.DTSVMState:
     """One Prop.-1 iteration (eqs. 6-9) on precomputed invariants.
 
-    Pure and traceable — the SPMD backend calls this inside shard_map
-    with a collective ``nbr_reduce`` and per-node invariant shards.
+    Pure and traceable — every backend steps it: the node-sharded one
+    inside shard_map with a collective ``nbr_reduce`` and per-node
+    invariant shards, the sample-sharded one with row panels and the
+    ``row_panels`` hook (:class:`RowPanels`; ``"fista"``/``"pg"``,
+    materialized operator).
 
     ``qp_precision`` / ``qp_operator`` select the mixed-precision and
     factored-matvec QP modes (validated by ``compile_problem``; both
@@ -155,7 +164,11 @@ def plan_step(prob: core.DTSVMProblem, inv: inv_lib.PlanInvariants,
     # the dual solve and its X^T Y lam summary (one launch in the fused
     # engines): the qp_solve phase
     with obs_spans.scope("qp_solve"):
-        if qp_operator == "factored":
+        if row_panels is not None:
+            lam = engine(inv.K, q, inv.hi, state.lam, iters=qp_iters,
+                         L=inv.L, gather=row_panels.gather)    # eq. (6)
+            zl = row_panels.zl(lam, Z)                         # X^T Y lam
+        elif qp_operator == "factored":
             lam, zl = engine.factored(Z, inv.a, q, inv.hi, state.lam,
                                       iters=qp_iters, L=inv.L)  # eq. (6)
         elif getattr(engine, "supports_fold", False):

@@ -90,38 +90,51 @@ def _prep(K, q, hi, lam0, L):
     return lam0, L
 
 
-def _vmapped(solve1, K, q, hi, lam0, L, iters):
-    """Apply a single-problem solver over the leading batch dims."""
-    fn = lambda Kb, qb, hb, l0, Lb: solve1(Kb, qb, hb, iters=iters,
-                                           lam0=l0, L=Lb)
-    for _ in range(K.ndim - 2):
+def _batched(recursion, matvec, ops, q, hi, lam0, L, iters):
+    """``recursion`` (``core.qp._fista`` / ``_pg``) on every problem of
+    the leading batch dims, K applied by ``matvec(*ops)`` of the
+    problem's operands."""
+    fn = lambda ob, qb, hb, l0, Lb: recursion(matvec(*ob), qb, hb, iters,
+                                              l0, Lb)
+    for _ in range(q.ndim - 1):
         fn = jax.vmap(fn)
-    return fn(K, q, hi, lam0, L)
+    return fn(ops, q, hi, lam0, L)
+
+
+def _k_matvec(gather):
+    """``K -> (y -> K y)``; with ``gather``, a row panel K[rows, :] times
+    the iterate's local rows joined over all rows (``plan.RowPanels``)."""
+    return lambda K: lambda y: jnp.matmul(
+        K, y if gather is None else gather(y), precision="highest")
+
+
+def _factored(recursion, Z, a, q, hi, lam0, iters, L):
+    """``recursion`` on K = Z diag(a) Z^T applied through its factors
+    (``core.qp.factored_matvec``); returns ``(lam, zl)``, zl = Z^T lam."""
+    if lam0 is None:
+        lam0 = jnp.zeros_like(q)
+    lam = _batched(recursion, qp_lib.factored_matvec, (Z, a), q, hi, lam0,
+                   L, iters)
+    # repro: noqa[raw-einsum-in-plan] — deliberate: plan_step's zl einsum, the same formula on the factored path
+    zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
+    return lam, zl
 
 
 @register("fista")
 def solve_fista(K, q, hi, lam0=None, *, iters: int,
-                L: Optional[jnp.ndarray] = None):
+                L: Optional[jnp.ndarray] = None, gather=None):
+    """``gather``: K holds row panels (``_k_matvec``)."""
     lam0, L = _prep(K, q, hi, lam0, L)
-    return _vmapped(qp_lib.solve_box_qp_fista, K, q, hi, lam0, L, iters)
+    return _batched(qp_lib._fista, _k_matvec(gather), (K,), q, hi, lam0, L,
+                    iters)
 
 
 def solve_fista_factored(Z, a, q, hi, lam0=None, *, iters: int, L):
     """:func:`solve_fista` on K = Z diag(a) Z^T applied through its
-    factors (``core.qp.solve_box_qp_fista_factored``, vmapped over the
-    batch dims): two reads of Z a step instead of one of K.  Returns
+    factors: two reads of Z a step instead of one of K.  Returns
     ``(lam, zl)`` with ``zl = Z^T lam``, as :func:`solve_factored_multi`
     does."""
-    if lam0 is None:
-        lam0 = jnp.zeros_like(q)
-    fn = lambda Zb, ab, qb, hb, l0, Lb: qp_lib.solve_box_qp_fista_factored(
-        Zb, ab, qb, hb, iters, l0, L=Lb)
-    for _ in range(q.ndim - 1):
-        fn = jax.vmap(fn)
-    lam = fn(Z, a, q, hi, lam0, L)
-    # repro: noqa[raw-einsum-in-plan] — deliberate: plan_step's zl einsum, the same formula solve_factored_multi ends with
-    zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
-    return lam, zl
+    return _factored(qp_lib._fista, Z, a, q, hi, lam0, iters, L)
 
 
 #: the engine's factored form (``Z``, ``a`` in place of K), which
@@ -131,9 +144,11 @@ solve_fista.factored = solve_fista_factored
 
 @register("pg")
 def solve_pg(K, q, hi, lam0=None, *, iters: int,
-             L: Optional[jnp.ndarray] = None):
+             L: Optional[jnp.ndarray] = None, gather=None):
+    """``gather``: as :func:`solve_fista`'s."""
     lam0, L = _prep(K, q, hi, lam0, L)
-    return _vmapped(qp_lib.solve_box_qp_pg, K, q, hi, lam0, L, iters)
+    return _batched(qp_lib._pg, _k_matvec(gather), (K,), q, hi, lam0, L,
+                    iters)
 
 
 @register("pallas_fused")
@@ -187,27 +202,11 @@ def solve_factored_multi(Z, a, q, hi, lam0=None, *, iters: int, L):
     K to derive it from — the invariant build streams |K| row sums
     without keeping the panels).
 
-    Returns ``(lam, zl)`` — the final-iterate w-update contraction
-    falls out of the last factored matvec's inner product for free.
-    NOT bitwise with the materialized path (the contraction reorders
-    the reduction by construction); validated by the BENCH_fit risk
-    deltas like the bf16 wire formats."""
-    if lam0 is None:
-        lam0 = jnp.zeros_like(q)
-    gamma = (1.0 / L)[..., None]                     # (..., 1) per problem
-    lam = jnp.clip(lam0, 0.0, hi)
-
-    def body(_, lam):
-        # repro: noqa[raw-einsum-in-plan] — deliberate: the factored operator's defining contraction; validated to float32 tolerance, never claimed bitwise vs the materialized plan
-        zt = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
-        # repro: noqa[raw-einsum-in-plan] — deliberate: second half of the O(ND) factored matvec (see above)
-        Klam = jnp.einsum("...nd,...d->...n", Z, a * zt, precision="highest")
-        return jnp.clip(lam + gamma * (q - Klam), 0.0, hi)
-
-    lam = jax.lax.fori_loop(0, iters, body, lam)
-    # repro: noqa[raw-einsum-in-plan] — deliberate: same formula as plan_step's zl einsum (the factored fold reuses the final Z^T lam)
-    zl = jnp.einsum("...n,...nd->...d", lam, Z, precision="highest")
-    return lam, zl
+    Returns ``(lam, zl)`` with ``zl = Z^T lam``.  NOT bitwise with the
+    materialized path (the contraction reorders the reduction by
+    construction); validated by the BENCH_fit risk deltas like the
+    bf16 wire formats."""
+    return _factored(qp_lib._pg, Z, a, q, hi, lam0, iters, L)
 
 
 solve_pallas_fused_multi.factored = solve_factored_multi   # its PG form

@@ -39,11 +39,13 @@ Taxonomy (the names the instrumented call sites use):
 name                    phase
 ======================  =================================================
 ``invariant_build``     span: ``engine.invariants.compute_invariants``
-                        (the K build, dense or budgeted); scope: the
-                        bodies of the jitted streamed builds and the
-                        dense Gram/Lipschitz pair
+                        (the K build, dense or budgeted) and the
+                        ``sample_shard`` panel build call; scope: the
+                        bodies of the jitted streamed builds, the
+                        dense Gram/Lipschitz pair and the panel build
 ``plan_replan``         span: ``Plan.replan`` (incremental rebuild)
-``scan_execute``        span: ``Plan.run``'s ADMM scan call, which times
+``scan_execute``        span: the ADMM scan call of ``Plan.run`` and of
+                        the ``sample_shard`` backend, which times
                         the scan's dispatch (and its trace, lowering and
                         compile on the first run of a shape, or on every
                         run with an ``eval_fn``) — not its execution,
@@ -51,8 +53,7 @@ name                    phase
                         scope: the scan body, whose device ops are the
                         execution
 ``qp_solve``            scope: the dual box-QP engine call of
-                        ``plan_step`` and the row-panel QP of the
-                        ``sample_shard`` backend
+                        ``plan_step`` with its ``X^T Y lam``
 ``consensus_update``    scope: eqs. (7)-(9), ``plan.consensus_update``
 ``neighbour_exchange``  scope: every neighbour sum (the ``nbr_reduce``
                         call sites) and the collectives of the sharded

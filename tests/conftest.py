@@ -12,7 +12,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(_HERE), "src"))
 
 #: the fit's cached programs: module-level jits that live for the process
 _FIT_PROGRAMS = (("repro.engine.plan", "_plan_scan"),
-                 ("repro.kernels.ops", "_gram_map"))
+                 ("repro.kernels.ops", "_gram_map"),
+                 ("repro.api.backends", "_panel_build"),
+                 ("repro.api.backends", "_panel_scan_jit"),
+                 ("repro.core.dtsvm_dist", "_node_build"),
+                 ("repro.core.dtsvm_dist", "_node_scan"))
 
 
 @pytest.fixture(autouse=True)

@@ -30,6 +30,47 @@ def test_dtsvm_dist_matches_reference(topology):
 
 
 @pytest.mark.slow
+def test_dtsvm_dist_second_run_lowers_nothing():
+    """The node-sharded build and scan are cached programs: a second
+    ``run_dtsvm_dist`` of one shape, on new data, lowers nothing, and
+    computes what the first program computes for it."""
+    out = run_with_devices("""
+        import numpy as np, jax
+        from repro import obs
+        from repro.core import dtsvm, dtsvm_dist, graph
+        from repro.data import synthetic
+        V, T = 4, 2
+        A = graph.ring(V)
+
+        def prob(seed):
+            data = synthetic.make_multitask_data(
+                V=V, T=T, p=6, n_train=np.full((V, T), 8, int), n_test=8,
+                seed=seed)
+            return dtsvm.make_problem(data["X"], data["y"], data["mask"], A)
+
+        p0, p1 = prob(0), prob(1)
+        dtsvm_dist.run_dtsvm_dist(p0, 3, topology="ring", qp_iters=10)
+        low = obs.compile_counts()["programs"]
+        assert low["jit(_node_build)"]["lower"]["count"] == 1
+        assert low["jit(_node_scan)"]["lower"]["count"] == 1
+        obs.clear_compiles()
+        st = dtsvm_dist.run_dtsvm_dist(p1, 3, topology="ring", qp_iters=10)
+        lowered = {prog: ph["lower"]["count"]
+                   for prog, ph in obs.compile_counts()["programs"].items()
+                   if "lower" in ph}
+        assert lowered == {}, lowered
+        dtsvm_dist._node_build.clear_cache()
+        dtsvm_dist._node_scan.clear_cache()
+        fresh = dtsvm_dist.run_dtsvm_dist(p1, 3, topology="ring",
+                                          qp_iters=10)
+        for a, b in zip(jax.tree.leaves(st), jax.tree.leaves(fresh)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        print("CACHED")
+    """, n_devices=4)
+    assert "CACHED" in out
+
+
+@pytest.mark.slow
 def test_consensus_trainer_agrees_and_learns():
     """ADMM-consensus training on a ring: loss decreases AND replicas
     converge toward consensus (gap shrinks) — the deep-net lift of the

@@ -467,11 +467,19 @@ def test_sample_shard_scopes_on_four_devices():
         api.DTSVM(SolverConfig(iters=2, qp_iters=4, backend="sample_shard",
                                backend_options={"n_shards": 4})).fit(
             data["X"], data["y"], data["mask"], adj)
-        (path,) = glob.glob(os.path.join(d, "*_jit_run_shard_compile.mlir"))
-        text = open(path).read()
-        for name in ("invariant_build", "scan_execute", "qp_solve",
-                     "consensus_update", "neighbour_exchange"):
-            assert re.search(r'loc\("[^"]*\b' + name + '/', text), name
+        # the fit's two programs: the panel build and the scan
+        (build,) = glob.glob(os.path.join(d,
+                                          "*_jit__panel_build_compile.mlir"))
+        (scan,) = glob.glob(os.path.join(d, "*_jit__panel_scan_compile.mlir"))
+        build, scan = open(build).read(), open(scan).read()
+        scoped = lambda text, name: re.search(
+            r'loc\("[^"]*\b' + name + '/', text)
+        for name in ("invariant_build", "neighbour_exchange"):
+            assert scoped(build, name), name
+        for name in ("scan_execute", "qp_solve", "consensus_update",
+                     "neighbour_exchange"):
+            assert scoped(scan, name), name
+        text = build + scan
         # every collective of the fit is named as the exchange
         coll = re.findall(r'loc\("([^"]*/(?:all_gather|pmax|psum))"', text)
         assert coll

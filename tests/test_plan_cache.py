@@ -1,7 +1,8 @@
 """The fit's programs are compiled once per shape, not once per fit.
 
 ``Plan.run``'s scan is the module-level jit ``engine.plan._plan_scan``
-and the Pallas branch of ``kernels.ops.weighted_gram`` maps over its
+(the ``sample_shard`` backend's are ``api.backends._panel_build`` and
+``_panel_scan_jit``) and the Pallas branch of ``kernels.ops.weighted_gram`` maps over its
 blocks inside the module-level jit ``kernels.ops._gram_map``.  Both are
 fed their arrays as arguments, so a second fit of the same shapes and
 QP configuration, on new data, lowers nothing; a changed shape or
@@ -23,6 +24,7 @@ from repro.kernels import ref
 V, T, N, P = 2, 2, 8, 4
 SCAN = "jit(_plan_scan)"
 GRAM_MAP = "jit(_gram_map)"
+PANEL_SCAN = "jit(_panel_scan)"
 
 
 def _data(seed=0, n=N):
@@ -44,10 +46,16 @@ def _first_fit(**kw):
     api.DTSVM(**kw).fit(*_data(0))
 
 
-def test_second_fit_of_a_shape_lowers_nothing():
-    kw = dict(iters=3, qp_iters=2)
+@pytest.mark.parametrize("backend,program", [
+    ("vmap", SCAN),
+    ("sample_shard", PANEL_SCAN),        # one device: n_shards=1
+])
+def test_second_fit_of_a_shape_lowers_nothing(backend, program):
+    kw = dict(iters=3, qp_iters=2, backend=backend)
+    if backend == "sample_shard":
+        kw["backend_options"] = {"n_shards": 1}
     _first_fit(**kw)
-    assert _lowerings(SCAN) == 1
+    assert _lowerings(program) == 1
     obs.clear_compiles()
     api.DTSVM(**kw).fit(*_data(1))             # same shapes, new data
     lowered = {prog: ph["lower"]["count"]
